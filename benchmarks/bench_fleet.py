@@ -8,7 +8,7 @@ their lattice evaluations, plus a few tenant-unique lattices) runs
 three ways:
 
   1. **baseline** — one in-process fault-free `CompileService`: the
-     reference responses.
+     reference responses. Like the workers, it computes on the CPU.
   2. **chaos fleet** — N worker subprocesses over a fresh shared store
      with the deterministic fault harness armed (`repro.testing.faults`):
      one worker hard-killed mid-wave after its second artifact publish,
@@ -196,6 +196,10 @@ def main() -> int:
                     help="small workload for CI")
     ap.add_argument("--out", default="results/benchmarks")
     args = ap.parse_args()
+    # the fleet's workers compute on the CPU (Fleet's default), so the
+    # baseline they must match bit for bit does too — and a parent that
+    # never touches the chip cannot hold it from a worker
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     res = collect(args.smoke)
     os.makedirs(args.out, exist_ok=True)
     with open(os.path.join(args.out, "bench_fleet.json"), "w") as f:

@@ -113,7 +113,7 @@ def _engine_inputs(B: int):
 def _bench_engines(B: int, n_steps: int, repeats: int) -> dict:
     """Identical lattice inputs through every (solver, precision) engine."""
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     from repro.core.spice.transient import Transient, crossing_time
 
     with enable_x64():
@@ -164,7 +164,7 @@ def _bench_engines(B: int, n_steps: int, repeats: int) -> dict:
 def _newton_parity() -> float:
     """Max |trace| gap between analytic-stamp Newton and jacfwd Newton."""
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     from repro.core import timing
     from repro.core.bank import BankConfig, build_bank
     from repro.core.spice.transient import Transient

@@ -16,12 +16,14 @@ is the store's atomic-rename publish. This module provides that claim:
     long device evaluation.
   * A lease whose mtime is older than `ttl_s` belongs to a DEAD worker.
     Anyone may steal it: take the per-key breaker lock (its own O_EXCL
-    file), RE-CHECK expiry under the lock, unlink, then race the normal
-    `O_CREAT | O_EXCL` claim. The re-check under mutual exclusion is
-    what makes stealing safe — a slow second stealer can never tear
-    down the fresh lease a quicker winner just created. A crashed
-    worker's in-flight nodes are therefore reclaimed after at most one
-    TTL, never lost.
+    file), RE-CHECK expiry under the lock, and atomically `os.replace`
+    the expired claim with a fresh one of its own before releasing the
+    lock. The re-check under mutual exclusion is what makes stealing
+    safe — a slow second stealer can never tear down the fresh lease a
+    quicker winner just created — and the in-place replace leaves no
+    moment without a claim file, so the breaker is the one and only
+    claimant credited with the steal. A crashed worker's in-flight
+    nodes are therefore reclaimed after at most one TTL, never lost.
   * `acquire(key, have)` is the waiter's loop: poll `have()` (usually a
     store read) until the owner publishes, or steal the lease once it
     expires. Callers must publish their own claimed work BEFORE waiting
@@ -131,16 +133,22 @@ class LeaseManager:
         except OSError:
             return False                 # vanished: claimable, not stale
 
-    def _break(self, path: str) -> bool:
-        """Remove an EXPIRED lease so it can be re-claimed. The caller's
+    def _write_claim(self, fd: int, key: str) -> None:
+        with os.fdopen(fd, "w") as f:
+            json.dump({"owner": self.owner, "key": key}, f)
+
+    def _steal(self, path: str, key: str) -> bool:
+        """Replace an EXPIRED lease with our own claim. The caller's
         expiry check races: by the time we act, a quicker stealer may
-        have broken the old lease AND someone may have re-claimed it
-        fresh. So removal happens under a per-key breaker lock (its own
-        `O_CREAT | O_EXCL` file) with expiry RE-CHECKED inside — of N
-        racing stealers at most one unlinks, and a fresh lease is never
-        torn down. A breaker orphaned by a crash mid-break expires like
-        a lease (its critical section is microseconds, so an old one is
-        always dead) and is cleared for the next pass."""
+        already have replaced the old lease. So the replace happens
+        under a per-key breaker lock (its own `O_CREAT | O_EXCL` file)
+        with expiry RE-CHECKED inside — of N racing stealers at most one
+        replaces, and a fresh lease is never torn down. The new claim is
+        written beside the old one and renamed over it, so the key is
+        never unclaimed in between. A breaker orphaned by a crash
+        mid-steal expires like a lease (its critical section is
+        microseconds, so an old one is always dead) and is cleared for
+        the next pass."""
         brk = path + ".brk"
         try:
             fd = os.open(brk, os.O_CREAT | os.O_EXCL | os.O_WRONLY)
@@ -157,9 +165,16 @@ class LeaseManager:
             os.close(fd)
             if not self._expired(path):
                 return False           # re-claimed while we raced
+            tmp = f"{path}.{uuid.uuid4().hex[:8]}.tmp"
             try:
-                os.unlink(path)
+                self._write_claim(
+                    os.open(tmp, os.O_CREAT | os.O_EXCL | os.O_WRONLY), key)
+                os.replace(tmp, path)
             except OSError:
+                try:
+                    os.unlink(tmp)
+                except OSError:
+                    pass
                 return False
             self.counts["broken"] += 1
             return True
@@ -178,29 +193,30 @@ class LeaseManager:
         Never blocks on a live owner."""
         os.makedirs(self.root, exist_ok=True)
         path = self._path(key)
-        stolen = False
         for _ in range(8):               # bounded retries around races
             try:
                 fd = os.open(path, os.O_CREAT | os.O_EXCL | os.O_WRONLY)
             except FileExistsError:
                 if not self._expired(path):
                     return None
-                if self._break(path):
-                    stolen = True
+                if self._steal(path, key):
+                    return self._hold(key, path, stolen=True)
                 continue                 # re-race the O_EXCL create
             except OSError:
                 return None
-            with os.fdopen(fd, "w") as f:
-                json.dump({"owner": self.owner, "key": key}, f)
-            lease = Lease(self, key, path, stolen)
-            with self._lock:
-                self._held[key] = lease
-            self.counts["claims"] += 1
-            if stolen:
-                self.counts["steals"] += 1
-            self._ensure_heartbeat()
-            return lease
+            self._write_claim(fd, key)
+            return self._hold(key, path, stolen=False)
         return None
+
+    def _hold(self, key: str, path: str, stolen: bool) -> Lease:
+        lease = Lease(self, key, path, stolen)
+        with self._lock:
+            self._held[key] = lease
+        self.counts["claims"] += 1
+        if stolen:
+            self.counts["steals"] += 1
+        self._ensure_heartbeat()
+        return lease
 
     def _release(self, lease: Lease) -> None:
         with self._lock:

@@ -31,10 +31,11 @@ class Query:
 @dataclass(frozen=True)
 class CompileQuery(Query):
     """One bank config -> full compiler report (netlists, floorplan,
-    timing/power/retention; optionally transient-simulated)."""
+    timing/power/retention; optionally transient-simulated, as a
+    one-lane lattice through the batched transient engine `solver`)."""
     cfg: BankConfig = BankConfig()
     simulate: bool = False
-    solver: str = "jnp"
+    solver: str = "pallas"
 
 
 @dataclass(frozen=True)

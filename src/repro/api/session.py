@@ -241,7 +241,7 @@ class Session:
     # eager convenience surface (thin wrappers over run())
     # ------------------------------------------------------------------
     def compile(self, cfg: Optional[BankConfig] = None, *, simulate=False,
-                solver="jnp", **cfg_kw) -> CompileResult:
+                solver="pallas", **cfg_kw) -> CompileResult:
         """One bank -> Report (netlists + floorplan + all reports).
         Accepts a BankConfig or BankConfig kwargs."""
         cfg = self._adopt(cfg if cfg is not None

@@ -30,6 +30,7 @@ from repro.core import power as power_mod
 from repro.core import retention as ret_mod
 from repro.core import timing as timing_mod
 from repro.core.bank import Bank, BankConfig, build_bank
+from repro.core.host import on_host
 from repro.core.spice.mna import Circuit
 
 
@@ -96,23 +97,29 @@ class Report:
 
 
 def compile_bank(cfg: BankConfig, *, simulate: bool = False,
-                 solver: str = "jnp") -> Report:
-    """Core compile flow (used by repro.api.Session.compile)."""
-    bank = build_bank(cfg)
-    t = timing_mod.analyze(bank)
+                 solver: str = "pallas") -> Report:
+    """Core compile flow (used by repro.api.Session.compile).
+    `simulate` characterizes the read column as a one-lane lattice
+    through `char_batch.characterize` with `solver`; the scalar
+    `timing.simulate_read` stays the test reference. The scalar model
+    runs on the host CPU device (`repro.core.host`)."""
     ret = None
     t_sim = None
     netlists = {}
-    if bank.is_gc:
-        ret = ret_mod.analyze(bank.cell, cfg.tech, wwlls=cfg.wwlls,
-                              wwl_boost=cfg.wwl_boost)
-        ckt, _ = timing_mod.read_netlist(bank)
-        netlists["read_column"] = circuit_to_spice(
-            ckt, f"{cfg.cell} {bank.rows}x{bank.cols} read column")
-        if simulate:
-            t_sim, _ = timing_mod.simulate_read(bank, solver=solver)
-    p = power_mod.analyze(bank, t.f_max_hz,
-                          t_ret_s=ret.t_ret_s if ret else None)
+    with on_host():
+        bank = build_bank(cfg)
+        t = timing_mod.analyze(bank)
+        if bank.is_gc:
+            ret = ret_mod.analyze(bank.cell, cfg.tech, wwlls=cfg.wwlls,
+                                  wwl_boost=cfg.wwl_boost)
+            ckt, _ = timing_mod.read_netlist(bank)
+            netlists["read_column"] = circuit_to_spice(
+                ckt, f"{cfg.cell} {bank.rows}x{bank.cols} read column")
+        p = power_mod.analyze(bank, t.f_max_hz,
+                              t_ret_s=ret.t_ret_s if ret else None)
+    if bank.is_gc and simulate:
+        from repro.core.spice.char_batch import characterize
+        t_sim = characterize([cfg], solver=solver)[0].t_cell_s
     return Report(cfg, bank, t, p, ret, t_sim, netlists)
 
 
@@ -122,7 +129,8 @@ class GCRAMCompiler:
     def __init__(self, cfg: BankConfig):
         self.cfg = cfg
 
-    def compile(self, *, simulate: bool = False, solver: str = "jnp") -> Report:
+    def compile(self, *, simulate: bool = False,
+                solver: str = "pallas") -> Report:
         warnings.warn(
             "GCRAMCompiler is deprecated; use repro.api.Session().compile("
             "cfg) or Session().run(CompileQuery(cfg))",

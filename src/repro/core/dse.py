@@ -34,6 +34,7 @@ from repro.core import retention as ret_mod
 from repro.core import timing as timing_mod
 from repro.core.bank import Bank, BankConfig, build_bank
 from repro.core.cells import CELLS, Bitcell, with_write_vt
+from repro.core.host import on_host
 from repro.core.techfile import SYN40, PHI_T
 
 
@@ -80,7 +81,13 @@ def evaluate(cfg: BankConfig, vdd_scale: float = 1.0) -> DesignPoint:
     """Scalar reference evaluation of one config at one operating voltage
     (`vdd_scale` multiplies tech.vdd; geometry/floorplan are voltage-
     independent). The batched evaluators in `repro.core.dse_batch` assert
-    parity against this function."""
+    parity against this function. Runs on the host CPU device
+    (`repro.core.host`)."""
+    with on_host():
+        return _evaluate(cfg, vdd_scale)
+
+
+def _evaluate(cfg: BankConfig, vdd_scale: float) -> DesignPoint:
     bank = build_bank(cfg)
     t = timing_mod.analyze(bank, vdd_scale=vdd_scale)
     if bank.is_gc:

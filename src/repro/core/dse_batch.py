@@ -14,7 +14,7 @@ lattice at once:
      settle);
   3. `jax.vmap` the per-point analytic timing + power algebra across the
      group's struct-of-arrays (rows, wire RC, word size, ...) in float64
-     (jax.experimental.enable_x64), reusing the formula kernels from
+     (jax.enable_x64), reusing the formula kernels from
      `repro.core.timing` — and vmap AGAIN over the vdd axis, whose
      per-scale constants ride in as mapped operands (geometry and wire
      RC are voltage-independent, so the structural arrays are shared
@@ -40,13 +40,14 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core import bank as bank_mod
 from repro.core import retention as ret_mod
 from repro.core import timing as timing_mod
 from repro.core.bank import BankConfig, build_bank
 from repro.core.dse import DesignPoint
+from repro.core.host import on_host
 from repro.core.power import PERIPH_LEAK_W_PER_UM2
 from repro.core.spice import devices as dv
 from repro.core.techfile import with_vdd_scale
@@ -99,7 +100,12 @@ def evaluate_batch(cfgs: Sequence[BankConfig],
 def _group_constants(cfg0: BankConfig, bank0, vdd_scale: float = 1.0) -> dict:
     """Electricals that depend only on (cell topology, operating voltage)
     — computed with the same scalar calls the reference `dse.evaluate`
-    path makes at that vdd_scale."""
+    path makes at that vdd_scale, on the host CPU device like it."""
+    with on_host():
+        return _group_constants_host(cfg0, bank0, vdd_scale)
+
+
+def _group_constants_host(cfg0: BankConfig, bank0, vdd_scale: float) -> dict:
     tech = with_vdd_scale(cfg0.tech, vdd_scale)
     cell = bank0.cell
     if bank0.is_gc:

@@ -28,7 +28,7 @@ replicates the exact staircase for parity testing against
 Everything here calls the shared formula kernels (`timing.elmore_delay`,
 `timing.cell_swing_time`, the EKV `channel_current` family) and the
 traced cell primitives (`cells.v_sn_written_t` &c): one algebra, two
-evaluation modes. Run under `jax.experimental.enable_x64` for
+evaluation modes. Run under `jax.enable_x64` for
 gradient-grade accuracy; the finite-difference harness in
 tests/test_grad_dse.py pins every output's derivative to < 1e-4.
 """

@@ -34,10 +34,11 @@ in a handful of compiled programs:
      (`transient.crossing_time`), interpolated between bracketing steps
      exactly like the scalar reference.
 
-Compiled programs are memoized per (topology, n_seg, n_steps, solver,
-precision), so
-repeated characterizations of overlapping lattices (Session sweeps,
-benchmarks) pay tracing once.
+Compiled programs are memoized per (netlist structure, n_seg, n_steps,
+solver, precision) — the fused engines take every element and device
+value as an operand, so all topology groups of one read-column
+structure share a program — and repeated characterizations of
+overlapping lattices (Session sweeps, benchmarks) pay tracing once.
 
 Newton Jacobian stamp math (the per-iteration hot path): the MNA Newton
 system is J dv = F(v) with J = C/h + G + dI/dv + gmin. dI/dv is built
@@ -54,15 +55,18 @@ from typing import Dict, List, Optional, Sequence
 
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core import timing as timing_mod
 from repro.core.bank import BankConfig, build_bank
+from repro.core.host import on_host
 from repro.core.dse_batch import (group_by_topology, pad_bucket,
                                   pow2_bucket, topology_key)
 from repro.core.spice.transient import Transient, crossing_time
+from repro.kernels.batched_solve.sparse import PARAM_FIELDS
 
 _PIPE_CACHE_MAX = 32     # compiled-pipeline entries kept (FIFO eviction)
+_DEV_KEYS = PARAM_FIELDS + ("ig",)
 
 
 @dataclass
@@ -88,11 +92,44 @@ class TransientChar:
 
 # (topology_key, n_seg, n_steps, solver) -> (system, Transient, stamps)
 _PIPE_CACHE: Dict[tuple, tuple] = {}
+# (netlist structure, solver, precision) -> Transient shared by every
+# topology group of that structure (fused engines only)
+_SHARED_TR: Dict[tuple, Transient] = {}
+
+
+def _structure_key(system) -> tuple:
+    """Everything a fused lattice program bakes in about its netlist:
+    node count, device terminals, sources, probes and the matrix
+    sparsity. Element and device VALUES are lattice operands, so the
+    topology groups of one read-column structure (every write-Vt flavor
+    and WWL boost setting of a cell, and cells that share a read scheme)
+    run the same compiled program."""
+    return (system.n,
+            tuple(tuple(np.asarray(system.didx[t]).tolist()) for t in "gab"),
+            tuple(np.asarray(system.src_node).tolist()),
+            tuple(np.asarray(system.src_wave).tolist()),
+            tuple(sorted(system.probes.items())),
+            (np.asarray(system.G) != 0).tobytes(),
+            (np.asarray(system.C) != 0).tobytes())
+
+
+def _device_batches(system, B: int, **over) -> dict:
+    """Every device parameter of `system` as a (B, n_dev) lattice
+    operand (`over` replaces entries), so the shared program takes the
+    group's own device values."""
+    n_dev = int(np.shape(system.dev["pol"])[-1])
+    out = {k: np.broadcast_to(np.asarray(system.dev[k]), (B, n_dev))
+           for k in _DEV_KEYS}
+    out.update(over)
+    return out
 
 
 def _pipeline(bank0, key: tuple):
     """Template netlist + jitted Transient + incidence stamps for one
     topology group (memoized: repeat characterizations re-trace nothing).
+    The fused engines share one Transient per netlist structure
+    (`_structure_key`); the dense "jnp" reference bakes the group's
+    device values and keeps its own.
 
     The key embeds id(tech) (via topology_key), so each entry also PINS
     the TechFile object: without the strong reference, a collected tech's
@@ -102,10 +139,18 @@ def _pipeline(bank0, key: tuple):
     if hit is not None:
         return hit[:-1]
     n_seg, n_steps, solver, precision = key[-4:]
-    ckt, meta = timing_mod.read_netlist(bank0, n_seg=n_seg)
-    res_stamps, cap_stamps, src_G = ckt.build_stamps()
-    system = ckt.build()
-    tr = Transient(system, solver=solver, precision=precision)
+    with on_host():
+        ckt, meta = timing_mod.read_netlist(bank0, n_seg=n_seg)
+        res_stamps, cap_stamps, src_G = ckt.build_stamps()
+        system = ckt.build()
+    if solver == "jnp":
+        tr = Transient(system, solver=solver, precision=precision)
+    else:
+        skey = (_structure_key(system), solver, precision)
+        tr = _SHARED_TR.get(skey)
+        if tr is None:
+            tr = _SHARED_TR[skey] = Transient(system, solver=solver,
+                                              precision=precision)
     out = (system, tr, res_stamps, cap_stamps, src_G, meta)
     while len(_PIPE_CACHE) >= _PIPE_CACHE_MAX:   # bound pinned programs
         del _PIPE_CACHE[next(iter(_PIPE_CACHE))]
@@ -142,13 +187,14 @@ def _characterize_group(cfgs: List[BankConfig], banks, *, n_seg: int,
     for p, bank in enumerate(banks):
         rc_p = (float(ext["bl_r_ohm"][p]), float(ext["bl_c_f"][p])) \
             if ext is not None else None
-        ckt_p, _ = timing_mod.read_netlist(bank, n_seg=n_seg, rc=rc_p)
+        with on_host():
+            ckt_p, _ = timing_mod.read_netlist(bank, n_seg=n_seg, rc=rc_p)
+            t_an[p] = timing_mod.cell_read_time(bank, rc=rc_p)[0]
         assert len(ckt_p.names) == len(system.names) and \
             len(ckt_p.res) == len(res_stamps) and \
             len(ckt_p.caps) == len(cap_stamps), "topology group mismatch"
         g_vals[p] = [g for _, _, g in ckt_p.res]
         c_vals[p] = [c for _, _, c in ckt_p.caps]
-        t_an[p] = timing_mod.cell_read_time(bank, rc=rc_p)[0]
 
     # float64 assembly, float64 all the way down (the group runs under
     # enable_x64 — see characterize; no f32 cast happens or should)
@@ -165,8 +211,9 @@ def _characterize_group(cfgs: List[BankConfig], banks, *, n_seg: int,
     wt = wv = None
     v_pre = 0.0
     for p in range(B):
-        waves_p, v_pre = timing_mod.read_stimulus(cell, tech,
-                                                  meta["v_sn"], t0[p])
+        with on_host():
+            waves_p, v_pre = timing_mod.read_stimulus(cell, tech,
+                                                      meta["v_sn"], t0[p])
         if wt is None:   # buffer dims derived from the stimulus itself
             k = max(len(t) for t, _ in waves_p)
             wt = np.zeros((B, len(waves_p), k))
@@ -187,8 +234,10 @@ def _characterize_group(cfgs: List[BankConfig], banks, *, n_seg: int,
     else:
         t_end_p = t_end
 
-    res = tr.run_lattice(wt, wv, t_end_p, n_steps,
-                         over_batches={"G": G_b, "C": C_b},
+    over = {"G": G_b, "C": C_b}
+    if solver != "jnp":
+        over.update(_device_batches(system, Bp))
+    res = tr.run_lattice(wt, wv, t_end_p, n_steps, over_batches=over,
                          v0=jnp.full((system.n,), v_pre))
 
     swing = tech.v_sense_se
@@ -232,7 +281,7 @@ def t_cell_grad_fn(cfg: BankConfig, *, n_seg: int = 8, n_steps: int = 300,
     count) are pinned at the NOMINAL design point: they are solver
     settings, not physics, and freezing them keeps the objective smooth.
 
-    Call under `jax.experimental.enable_x64` (gradients of interpolated
+    Call under `jax.enable_x64` (gradients of interpolated
     crossings through a cond(J)~1e6 system need f64). Gain cells only;
     solver must be "pallas" or "sparse" (the dense "jnp" path takes no
     device-parameter overrides).
@@ -254,7 +303,9 @@ def t_cell_grad_fn(cfg: BankConfig, *, n_seg: int = 8, n_steps: int = 300,
     # ladder caps (c_bl/n_seg each), the SA input cap, 4 read-device caps
     # (each proportional to w_read). Assert that layout before relying
     # on it.
-    ckt0, _ = timing_mod.read_netlist(bank0, n_seg=n_seg)
+    with on_host():
+        ckt0, _ = timing_mod.read_netlist(bank0, n_seg=n_seg)
+        t_an0 = timing_mod.cell_read_time(bank0)[0]
     g0 = np.array([g for _, _, g in ckt0.res])          # conductances
     c0 = np.array([c for _, _, c in ckt0.caps])
     assert len(g0) == n_seg and len(c0) == n_seg + 9, \
@@ -272,7 +323,6 @@ def t_cell_grad_fn(cfg: BankConfig, *, n_seg: int = 8, n_steps: int = 300,
     n_dev = len(w0)
 
     # -- static discretization (from the nominal analytic estimate)
-    t_an0 = timing_mod.cell_read_time(bank0)[0]
     t_end = max(timing_mod.T_END_OVER_ANALYTIC * t_an0,
                 timing_mod.T_END_MIN_S)
     t0 = timing_mod.T0_FRACTION * t_end
@@ -334,7 +384,9 @@ def t_cell_grad_fn(cfg: BankConfig, *, n_seg: int = 8, n_steps: int = 300,
             w0[d_rd] * s_w)
         v0 = jnp.broadcast_to(v_pre[:, None], (B, n))
         res = tr.run_lattice(wt, wv, jnp.full((B,), t_end), n_steps,
-                             over_batches={"G": G_b, "C": C_b, "w": w_b},
+                             over_batches={"G": G_b, "C": C_b,
+                                           **_device_batches(system, B,
+                                                             w=w_b)},
                              v0=v0)
         # per-point sense target via trace shift (crossing_time takes a
         # scalar target)
@@ -344,6 +396,38 @@ def t_cell_grad_fn(cfg: BankConfig, *, n_seg: int = 8, n_steps: int = 300,
         return tc - t0, valid
 
     return fn
+
+
+def lattice_program(cfg: BankConfig, B: int, *, n_seg: int = 8,
+                    n_steps: int = 300, solver: str = "pallas",
+                    precision: str = "f64", sharding=None):
+    """The lowered program `characterize` runs for `cfg`'s topology group
+    at a lattice bucket of B points, built from shapes alone — on
+    `sharding`'s device when given (a described chip need not be
+    attached), else on the default device. `.compile().as_text()` shows
+    which engine the compiler got: `tpu_custom_call` marks the Pallas
+    kernel. Fused solvers only."""
+    if solver == "jnp":
+        raise ValueError("the dense 'jnp' reference has no fused lattice "
+                         "program")
+    import jax
+    with enable_x64():
+        bank0 = build_bank(cfg)
+        key = topology_key(cfg) + (n_seg, n_steps, solver, precision)
+        system, tr, *_, meta = _pipeline(bank0, key)
+        waves, _ = timing_mod.read_stimulus(bank0.cell, cfg.tech,
+                                            meta["v_sn"], 0.0)
+        n_w, k = len(waves), max(len(t) for t, _ in waves)
+        n, n_dev = system.n, int(np.shape(system.dev["pol"])[-1])
+
+        def S(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.float64,
+                                        sharding=sharding)
+
+        dev_keys = tuple(sorted(_DEV_KEYS))
+        return tr._fused_fn(n_steps, dev_keys).lower(
+            S(B), S(B, n_w, k), S(B, n_w, k), S(n), S(B, n, n), S(B, n, n),
+            tuple(S(B, n_dev) for _ in dev_keys))
 
 
 def characterize(cfgs: Sequence[BankConfig], *, n_steps: int = 300,

@@ -43,6 +43,16 @@ def channel_current_raw(pol, vt0, n, kp, lam, w, l, vg, va, vb):
     return w * jnp.where(va >= vb, mag(va, vb), -mag(vb, va))
 
 
+def _vgs_partials(is_n, vg):
+    """d(vgs_on)/d{vg, v_hi, v_lo}: (1, 0, -1) for n-type, (-1, 1, 0) for
+    p-type, in vg's dtype (python-scalar branches would be f64 under
+    enable_x64, which the TPU kernel compiler cannot lay out)."""
+    one = jnp.ones_like(vg)
+    zero = jnp.zeros_like(vg)
+    return (jnp.where(is_n, one, -one), jnp.where(is_n, zero, one),
+            jnp.where(is_n, -one, zero))
+
+
 def channel_current_grads(pol, vt0, n, kp, lam, w, l, vg, va, vb):
     """Closed-form (di/dvg, di/dva, di/dvb) of `channel_current_raw`,
     vectorized over device arrays — one pass computes every device's 3x3
@@ -74,9 +84,7 @@ def channel_current_grads(pol, vt0, n, kp, lam, w, l, vg, va, vb):
         core = sp_a ** 2 - sp_b ** 2
         lam_f = 1.0 + lam * vds
         # d(vgs_on)/d{vg, v_hi, v_lo}
-        dvgs_dvg = jnp.where(is_n, 1.0, -1.0)
-        dvgs_dhi = jnp.where(is_n, 0.0, 1.0)
-        dvgs_dlo = jnp.where(is_n, -1.0, 0.0)
+        dvgs_dvg, dvgs_dhi, dvgs_dlo = _vgs_partials(is_n, vg)
         dm_dvg = i_s * (dl2a - dl2b) * dvgs_dvg / den * lam_f
         dm_dhi = i_s * ((dl2a * dvgs_dhi - dl2b * (dvgs_dhi - n)) / den
                         * lam_f + core * lam)
@@ -116,9 +124,7 @@ def channel_current_and_grads(pol, vt0, n, kp, lam, w, l, vg, va, vb):
         core = sp_a ** 2 - sp_b ** 2
         lam_f = 1.0 + lam * vds
         m = i_s * core * lam_f
-        dvgs_dvg = jnp.where(is_n, 1.0, -1.0)
-        dvgs_dhi = jnp.where(is_n, 0.0, 1.0)
-        dvgs_dlo = jnp.where(is_n, -1.0, 0.0)
+        dvgs_dvg, dvgs_dhi, dvgs_dlo = _vgs_partials(is_n, vg)
         dm_dvg = i_s * (dl2a - dl2b) * dvgs_dvg / den * lam_f
         dm_dhi = i_s * ((dl2a * dvgs_dhi - dl2b * (dvgs_dhi - n)) / den
                         * lam_f + core * lam)
@@ -126,13 +132,15 @@ def channel_current_and_grads(pol, vt0, n, kp, lam, w, l, vg, va, vb):
                         * lam_f - core * lam)
         return m, dm_dvg, dm_dhi, dm_dlo
 
-    f_m, f_dvg, f_dhi, f_dlo = mag_all(va, vb)
-    r_m, r_dvg, r_dhi, r_dlo = mag_all(vb, va)
+    # orient first, evaluate once: the same values as evaluating both
+    # directions and selecting, at half the transcendentals
     fwd = va >= vb
-    i = w * jnp.where(fwd, f_m, -r_m)
-    di_dvg = w * jnp.where(fwd, f_dvg, -r_dvg)
-    di_dva = w * jnp.where(fwd, f_dhi, -r_dlo)
-    di_dvb = w * jnp.where(fwd, f_dlo, -r_dhi)
+    m, dm_dvg, dm_dhi, dm_dlo = mag_all(jnp.where(fwd, va, vb),
+                                        jnp.where(fwd, vb, va))
+    i = w * jnp.where(fwd, m, -m)
+    di_dvg = w * jnp.where(fwd, dm_dvg, -dm_dvg)
+    di_dva = w * jnp.where(fwd, dm_dhi, -dm_dlo)
+    di_dvb = w * jnp.where(fwd, dm_dlo, -dm_dhi)
     return i, di_dvg, di_dva, di_dvb
 
 

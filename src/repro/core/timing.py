@@ -285,7 +285,7 @@ def simulate_read(bank, n_steps=300, t_end=None, solver="jnp"):
     relative noise into the traces — double precision is what makes this
     path the accuracy ANCHOR the analytic model calibrates against (and
     what the batched lattice pipeline asserts 1% parity with)."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     with enable_x64():
         return _simulate_read_x64(bank, n_steps, t_end, solver)
 

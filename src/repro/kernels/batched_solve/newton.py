@@ -38,12 +38,24 @@ channel partials and e_g = (1,-1/2,-1/2) the gate-leak row — so (I+DS)
 assembles from two outer products per device, no 3x3 stamps are ever
 materialized.
 
+The iteration is written in LANE form: every per-point quantity is one
+array over the lattice lanes, and the small matrix algebra (the (n, k)
+Woodbury factors, the (k, k) capacitance matrix and its closed-form
+solve) is unrolled over static indices into elementwise multiply-adds.
+Nothing contracts a batched dimension, so the same body lowers through
+XLA on any backend and through Mosaic (which refuses batched
+contractions such as `bdj,bdjk->bdk`) inside the TPU kernel, where each
+lane array is one (8, 128) vreg tile of 1024 lattice points. The
+step-constant operands travel coefficient-major (`pack_coefs`): row c
+holds one scalar coefficient for every lane.
+
 The same traced body runs three ways: under `jax.lax.while_loop` with a
-whole-batch early exit (the XLA fallback, production path on CPU), under
-a fixed-length `fori_loop` inside the Pallas kernel (`fused.py`), and in
-interpret mode for the CPU parity tests. Per-lane freeze (`done` mask)
-makes all three bit-identical: a converged lane stops changing, so an
-early-exited while_loop and a run-to-the-cap fori_loop agree exactly.
+whole-batch early exit (the XLA path: CPU, and the f64/mixed specs on
+TPU), under a fixed-length `fori_loop` inside the Pallas kernel
+(`fused.py`, f32 specs on TPU), and in interpret mode for the CPU parity
+tests. Per-lane freeze (`done` mask) makes all three bit-identical: a
+converged lane stops changing, so an early-exited while_loop and a
+run-to-the-cap fori_loop agree exactly.
 
 Precision policy (docs/fidelity-tiers.md): `store_dtype` is the dtype of
 the carried state/traces, `compute_dtype` the dtype of the model
@@ -54,6 +66,7 @@ round-off into the traces).
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -65,9 +78,9 @@ from repro.core.spice.mna import (G_MIN, channel_current_and_grads,
 from repro.kernels.batched_solve.sparse import (PARAM_FIELDS, PRECISIONS,
                                                 pack_params)
 
-__all__ = ["FusedSpec", "build_fused_spec", "precompute", "make_fused_iter",
-           "newton_solve", "newton_solve_fixed", "pack_params",
-           "residual", "fixed_point_adjoint"]
+__all__ = ["FusedSpec", "build_fused_spec", "precompute", "pack_coefs",
+           "make_fused_iter", "newton_solve", "newton_solve_fixed",
+           "pack_params", "residual", "fixed_point_adjoint"]
 
 #: KCL row signs of the channel current (rows a, b, g)
 S_A = np.array([1.0, -1.0, 0.0])
@@ -156,7 +169,7 @@ def precompute(spec: FusedSpec, G_b, C_b, h):
     C_b = jnp.asarray(C_b, cdt)
     h = jnp.asarray(h, cdt)
     J0 = G_b + C_b / h[:, None, None] + G_MIN * jnp.eye(n, dtype=cdt)
-    K = jnp.linalg.inv(J0)
+    K = _inv_dominant(J0)
     KU = jnp.einsum("bij,jk->bik", K, jnp.asarray(spec.um, cdt))
     Sb = jnp.einsum("ki,bij->bkj", jnp.asarray(spec.vm, cdt), KU)
     if spec.n_dev:
@@ -171,121 +184,230 @@ def precompute(spec: FusedSpec, G_b, C_b, h):
     }
 
 
+def _inv_dominant(J):
+    """Inverse of a (B, n, n) batch of strictly diagonally dominant
+    matrices by unpivoted Gauss-Jordan, unrolled over the static n.
+    J0 = G + C/h + gmin is dominant (nodal Laplacians plus positive
+    diagonal stamps), so no pivoting is needed — and none of the
+    elementwise steps needs an LU custom call, which XLA:TPU offers in
+    f32 only."""
+    n = J.shape[-1]
+    A = J
+    X = jnp.broadcast_to(jnp.eye(n, dtype=J.dtype), J.shape)
+    for p in range(n):
+        inv_p = 1.0 / A[:, p, p:p + 1]
+        row_a = A[:, p, :] * inv_p
+        row_x = X[:, p, :] * inv_p
+        f = A[:, :, p].at[:, p].set(0.0)[:, :, None]
+        A = (A - f * row_a[:, None, :]).at[:, p, :].set(row_a)
+        X = (X - f * row_x[:, None, :]).at[:, p, :].set(row_x)
+    return X
+
+
+def pack_coefs(spec: FusedSpec, pre, params):
+    """Step-constant operands of the fused iteration, coefficient-major:
+    (C, B) in the compute dtype, row c one scalar coefficient of every
+    lane. Row order (see `_CoefIndex`): KU (n, k), Sb (n_dev, 3, k),
+    KPa (n, n_dev), KPg (n, n_dev), params (N_PARAMS, n_dev)."""
+    _, cdt = spec.dtypes
+    B = params.shape[0]
+    if not spec.n_dev:
+        return jnp.zeros((0, B), cdt)
+    blocks = (pre["KU"], pre["Sb"], pre["KPa"], pre["KPg"], params)
+    return jnp.concatenate(
+        [x.astype(cdt).reshape(B, -1).T for x in blocks], axis=0)
+
+
+class _CoefIndex:
+    """Row offsets into the `pack_coefs` layout."""
+
+    def __init__(self, spec: FusedSpec):
+        n, nd, k = spec.n, spec.n_dev, spec.k
+        self.nd, self.k = nd, k
+        self.s = n * k
+        self.pa = self.s + nd * 3 * k
+        self.pg = self.pa + n * nd
+        self.p = self.pg + n * nd
+
+    def ku(self, i, c):
+        return i * self.k + c
+
+    def sb(self, d, j, c):
+        return self.s + (3 * d + j) * self.k + c
+
+    def kpa(self, i, d):
+        return self.pa + i * self.nd + d
+
+    def kpg(self, i, d):
+        return self.pg + i * self.nd + d
+
+    def param(self, f, d):
+        return self.p + f * self.nd + d
+
+
+def _dot(xs, ys):
+    """sum_j xs[j] * ys[j] over lane arrays, accumulated in index order."""
+    acc = xs[0] * ys[0]
+    for x, y in zip(xs[1:], ys[1:]):
+        acc = acc + x * y
+    return acc
+
+
+def _matvec(M, x):
+    return [_dot(row, x) for row in M]
+
+
+def _matmul(X, Y):
+    cols = list(zip(*Y))
+    return [[_dot(row, col) for col in cols] for row in X]
+
+
 def _inv3(M):
-    """Closed-form batched 3x3 inverse (adjugate via cross products) —
-    branch-free, no per-pivot unrolling."""
-    r0 = jnp.cross(M[..., 1, :], M[..., 2, :])
-    r1 = jnp.cross(M[..., 2, :], M[..., 0, :])
-    r2 = jnp.cross(M[..., 0, :], M[..., 1, :])
-    det = jnp.sum(M[..., 0, :] * r0, axis=-1)
-    return jnp.stack([r0, r1, r2], axis=-1) / det[..., None, None]
+    """Closed-form 3x3 inverse (adjugate via cross products) of a nested
+    list of lane arrays — branch-free, no per-pivot unrolling."""
+    def cross(u, v):
+        return [u[1] * v[2] - u[2] * v[1], u[2] * v[0] - u[0] * v[2],
+                u[0] * v[1] - u[1] * v[0]]
+
+    r = (cross(M[1], M[2]), cross(M[2], M[0]), cross(M[0], M[1]))
+    det = _dot(M[0], r[0])
+    return [[r[j][i] / det for j in range(3)] for i in range(3)]
 
 
 def _solve_small(A, b, n_dev: int):
-    """w = A^-1 b for the (B, k, k) Woodbury capacitance matrix
-    A = I + D S. k = 3 n_dev is tiny; specialize the common shapes
-    (closed-form 3x3 blocks) and fall back to unrolled unpivoted
-    elimination for larger device counts (A is a small perturbation of
-    the identity in the circuits this engine targets)."""
+    """w = A^-1 b for the k x k Woodbury capacitance matrix A = I + D S,
+    given as a nested list of lane arrays. k = 3 n_dev is tiny;
+    specialize the common shapes (closed-form 3x3 blocks, block-Schur
+    for two devices) and fall back to unrolled unpivoted elimination for
+    larger device counts (A is a small perturbation of the identity in
+    the circuits this engine targets)."""
     if n_dev == 1:
-        return jnp.einsum("bij,bj->bi", _inv3(A), b)
+        return _matvec(_inv3(A), b)
     if n_dev == 2:
-        P, Q = A[:, :3, :3], A[:, :3, 3:]
-        R, T = A[:, 3:, :3], A[:, 3:, 3:]
+        P = [row[:3] for row in A[:3]]
+        Q = [row[3:] for row in A[:3]]
+        R = [row[:3] for row in A[3:]]
+        T = [row[3:] for row in A[3:]]
         Pi = _inv3(P)
-        X = jnp.einsum("bij,bjk->bik", Pi, Q)
-        y1 = jnp.einsum("bij,bj->bi", Pi, b[:, :3])
-        x2 = jnp.einsum(
-            "bij,bj->bi",
-            _inv3(T - jnp.einsum("bij,bjk->bik", R, X)),
-            b[:, 3:] - jnp.einsum("bij,bj->bi", R, y1))
-        x1 = y1 - jnp.einsum("bij,bj->bi", X, x2)
-        return jnp.concatenate([x1, x2], axis=1)
+        X = _matmul(Pi, Q)
+        y1 = _matvec(Pi, b[:3])
+        RX = _matmul(R, X)
+        Ry1 = _matvec(R, y1)
+        x2 = _matvec(
+            _inv3([[T[i][j] - RX[i][j] for j in range(3)] for i in range(3)]),
+            [b[3 + i] - Ry1[i] for i in range(3)])
+        Xx2 = _matvec(X, x2)
+        return [y1[i] - Xx2[i] for i in range(3)] + x2
     k = 3 * n_dev
+    A = [list(row) for row in A]
+    b = list(b)
     for i in range(k):
-        f = A[:, i + 1:, i] / A[:, i, i:i + 1]
-        A = A.at[:, i + 1:, i:].add(-f[:, :, None] * A[:, i:i + 1, i:])
-        b = b.at[:, i + 1:].add(-f * b[:, i:i + 1])
-    x = jnp.zeros_like(b)
+        for r in range(i + 1, k):
+            f = A[r][i] / A[i][i]
+            for c in range(i + 1, k):
+                A[r][c] = A[r][c] - f * A[i][c]
+            b[r] = b[r] - f * b[i]
+    x = [None] * k
     for i in range(k - 1, -1, -1):
-        s = b[:, i] - jnp.sum(A[:, i, i + 1:] * x[:, i + 1:], axis=1)
-        x = x.at[:, i].set(s / A[:, i, i])
+        s_ = b[i]
+        for c in range(i + 1, k):
+            s_ = s_ - A[i][c] * x[c]
+        x[i] = s_ / A[i][i]
     return x
 
 
 def make_fused_iter(spec: FusedSpec, tol: float):
-    """Returns iter_fn(pre, Krhs, params, v, done) -> (v, done): one
-    fused Woodbury-Newton step. `pre` from `precompute`, Krhs (B, n) the
-    per-timestep hoist K @ rhs, params (B, N_PARAMS, n_dev) from
-    `pack_params`, v (B, n) store-dtype state, done (B,) freeze mask.
+    """Returns iter_fn(coef, krhs, v, done) -> (v, done): one fused
+    Woodbury-Newton step in lane form. `coef` is anything whose [c]
+    yields coefficient row c over the lanes — the (C, B) array from
+    `pack_coefs`, or its (C, rows, 128) block ref inside the Pallas
+    kernel; `krhs` likewise holds the per-timestep hoist K @ rhs
+    row-wise (n rows, compute dtype). `v` is a tuple of n store-dtype
+    lane arrays, `done` a boolean lane array (per-lane freeze).
 
-    The body is deliberately CONSTANT-FREE: Pallas rejects kernels that
-    capture array literals, so the terminal gathers unroll over the
-    (static, tiny) device list instead of index arrays, the S_A/S_G/E_G
-    sign vectors enter as python scalar coefficients in explicit row
-    stacks, and the Woodbury identity comes from broadcasted_iota. The
-    values are bit-compatible with the einsum formulation (the sign
-    entries are exact binary fractions)."""
+    The body is CONSTANT-FREE: Pallas rejects kernels that capture array
+    literals, so terminal gathers resolve to static node indices (ground
+    reads a zero lane array), the S_A/S_G/E_G sign vectors enter as
+    python scalar coefficients, and the Woodbury identity is a python
+    `1.0 +` on the diagonal."""
     sdt, cdt = spec.dtypes
-    n_dev, k = spec.n_dev, spec.k
+    n, n_dev, k = spec.n, spec.n_dev, spec.k
+    ix = _CoefIndex(spec)
     # host-side static node indices per device terminal (-1 = ground)
-    g_idx = [int(i) if i < spec.n else -1 for i in spec.g_safe]
-    a_idx = [int(i) if i < spec.n else -1 for i in spec.a_safe]
-    b_idx = [int(i) if i < spec.n else -1 for i in spec.b_safe]
+    g_idx = [int(i) if i < n else -1 for i in spec.g_safe]
+    a_idx = [int(i) if i < n else -1 for i in spec.a_safe]
+    b_idx = [int(i) if i < n else -1 for i in spec.b_safe]
+    nf = len(PARAM_FIELDS)
 
-    def gather(x, idx):
-        """(B, n) -> (B, n_dev) terminal values; ground reads 0."""
-        cols = [x[:, i] if i >= 0 else jnp.zeros_like(x[:, 0])
-                for i in idx]
-        return jnp.stack(cols, axis=1)
+    def iter_fn(coef, krhs, v, done):
+        vc = [x.astype(cdt) for x in v]
 
-    def iter_fn(pre, Krhs, params, v, done):
-        B = v.shape[0]
-        vc = v.astype(cdt)
+        @functools.lru_cache(maxsize=None)
+        def cf(c):                      # each coefficient row loads once
+            return coef[c]
+
+        def freeze(dv):
+            return tuple(jnp.where(done, v[i], (vc[i] - dv[i]).astype(sdt))
+                         for i in range(n))
+
         if n_dev == 0:      # linear circuit: one exact solve
-            dv = vc - Krhs.astype(cdt)
-            v_next = jnp.where(done[:, None], v, (vc - dv).astype(sdt))
-            return v_next, done | jnp.ones((B,), bool)
-        vg = gather(vc, g_idx)
-        va = gather(vc, a_idx)
-        vb = gather(vc, b_idx)
-        p = params.astype(cdt)
-        i_ab, di_dvg, di_dva, di_dvb = channel_current_and_grads(
-            *(p[:, i] for i in range(len(PARAM_FIELDS))), vg, va, vb)
-        gg = p[:, len(PARAM_FIELDS)]
-        i_g = gg * (vg - 0.5 * (va + vb))
-        d3 = jnp.stack([di_dvg, di_dva, di_dvb], axis=2)  # (B, n_dev, 3)
-        Sb = pre["Sb"].astype(cdt)
-        t = (vc - Krhs.astype(cdt)
-             + jnp.einsum("bid,bd->bi", pre["KPa"].astype(cdt), i_ab)
-             + jnp.einsum("bid,bd->bi", pre["KPg"].astype(cdt), i_g))
+            dv = [vc[i] - krhs[i] for i in range(n)]
+            return freeze(dv), done | jnp.ones_like(done)
+        zero = jnp.zeros_like(vc[0])
+
+        def pick(xs, idx):
+            return [xs[i] if i >= 0 else zero for i in idx]
+
+        vg, va, vb = pick(vc, g_idx), pick(vc, a_idx), pick(vc, b_idx)
+        i_ab, i_g, gg, d3 = [], [], [], []
+        for d in range(n_dev):
+            i_d, dg, da, db = channel_current_and_grads(
+                *(cf(ix.param(f, d)) for f in range(nf)),
+                vg[d], va[d], vb[d])
+            gg.append(cf(ix.param(nf, d)))
+            i_ab.append(i_d)
+            i_g.append(gg[d] * (vg[d] - 0.5 * (va[d] + vb[d])))
+            d3.append((dg, da, db))
+        t = [vc[i] - krhs[i]
+             + _dot([cf(ix.kpa(i, d)) for d in range(n_dev)], i_ab)
+             + _dot([cf(ix.kpg(i, d)) for d in range(n_dev)], i_g)
+             for i in range(n)]
         # Vm @ t rows are one-hot terminal picks (g, a, b) per device
-        g3 = jnp.stack([gather(t, g_idx), gather(t, a_idx),
-                        gather(t, b_idx)], axis=2)        # (B, n_dev, 3)
-        # D = s_a (x) d3 + s_g (x) gg*e_g per device block (rank 2);
-        # e_g = (1, -1/2, -1/2) over Sb's terminal axis (g, a, b)
-        d3S = jnp.einsum("bdj,bdjk->bdk", d3, Sb)         # (B, n_dev, k)
-        egS = (Sb[:, :, 0] - 0.5 * Sb[:, :, 1] - 0.5 * Sb[:, :, 2]) \
-            * gg[:, :, None]
-        # rows (a, b, g): s_a = (1, -1, 0), s_g = (-1/2, -1/2, 1)
-        DS = jnp.stack([d3S - 0.5 * egS,
-                        -d3S - 0.5 * egS,
-                        egS], axis=2).reshape(B, k, k)
-        rows = jax.lax.broadcasted_iota(jnp.int32, (k, k), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (k, k), 1)
-        A = (rows == cols).astype(cdt)[None] + DS
-        d3g = jnp.einsum("bdj,bdj->bd", d3, g3)
-        egg = (g3[:, :, 0] - 0.5 * g3[:, :, 1] - 0.5 * g3[:, :, 2]) * gg
-        b_k = jnp.stack([d3g - 0.5 * egg,
-                         -d3g - 0.5 * egg,
-                         egg], axis=2).reshape(B, k)
+        tg, ta, tb = pick(t, g_idx), pick(t, a_idx), pick(t, b_idx)
+        # A = I + D S with D = s_a (x) d3 + s_g (x) gg*e_g per device
+        # block (rank 2); e_g = (1, -1/2, -1/2) over the terminal axis
+        # (g, a, b), KCL rows (a, b, g): s_a = (1, -1, 0),
+        # s_g = (-1/2, -1/2, 1)
+        A, b_k = [], []
+        for d in range(n_dev):
+            rows = ([], [], [])
+            for c in range(k):
+                sb = [cf(ix.sb(d, j, c)) for j in range(3)]
+                d3S = _dot(list(d3[d]), sb)
+                egS = (sb[0] - 0.5 * sb[1] - 0.5 * sb[2]) * gg[d]
+                rows[0].append(d3S - 0.5 * egS)
+                rows[1].append(-d3S - 0.5 * egS)
+                rows[2].append(egS)
+            A.extend(rows)
+            d3g = _dot(list(d3[d]), [tg[d], ta[d], tb[d]])
+            egg = (tg[d] - 0.5 * ta[d] - 0.5 * tb[d]) * gg[d]
+            b_k.extend([d3g - 0.5 * egg, -d3g - 0.5 * egg, egg])
+        for r in range(k):
+            A[r][r] = 1.0 + A[r][r]
         w = _solve_small(A, b_k, n_dev)
-        dv = t - jnp.einsum("bnk,bk->bn", pre["KU"].astype(cdt), w)
-        conv = jnp.max(jnp.abs(dv), axis=1) < tol
-        v_next = jnp.where(done[:, None], v, (vc - dv).astype(sdt))
-        return v_next, done | conv
+        dv = [t[i] - _dot([cf(ix.ku(i, c)) for c in range(k)], w)
+              for i in range(n)]
+        err = functools.reduce(jnp.maximum, [jnp.abs(x) for x in dv])
+        return freeze(dv), done | (err < tol)
 
     return iter_fn
+
+
+def _lanes_in(spec: FusedSpec, pre, Krhs, params, v0):
+    """Dense (B, ...) operands -> the lane form `make_fused_iter` takes."""
+    _, cdt = spec.dtypes
+    return (pack_coefs(spec, pre, params), Krhs.astype(cdt).T,
+            tuple(v0[:, i] for i in range(spec.n)))
 
 
 def newton_solve(spec: FusedSpec, pre, Krhs, params, v0,
@@ -294,6 +416,7 @@ def newton_solve(spec: FusedSpec, pre, Krhs, params, v0,
     early exit. Per-lane freeze makes the result bit-identical to the
     fixed-length variant the Pallas kernel runs."""
     it = make_fused_iter(spec, tol)
+    coef, krhs, v = _lanes_in(spec, pre, Krhs, params, v0)
 
     def cond(state):
         _, done, i = state
@@ -301,13 +424,13 @@ def newton_solve(spec: FusedSpec, pre, Krhs, params, v0,
 
     def body(state):
         v, done, i = state
-        v, done = it(pre, Krhs, params, v, done)
+        v, done = it(coef, krhs, v, done)
         return v, done, i + 1
 
     B = v0.shape[0]
     v, _, n_it = jax.lax.while_loop(
-        cond, body, (v0, jnp.zeros((B,), bool), jnp.asarray(0)))
-    return v, n_it
+        cond, body, (v, jnp.zeros((B,), bool), jnp.asarray(0)))
+    return jnp.stack(v, axis=1), n_it
 
 
 def newton_solve_fixed(spec: FusedSpec, pre, Krhs, params, v0,
@@ -316,15 +439,16 @@ def newton_solve_fixed(spec: FusedSpec, pre, Krhs, params, v0,
     control flow the Pallas kernel uses; parity tests run this against
     the kernel in interpret mode."""
     it = make_fused_iter(spec, tol)
+    coef, krhs, v = _lanes_in(spec, pre, Krhs, params, v0)
     B = v0.shape[0]
 
     def body(_, state):
         v, done = state
-        return it(pre, Krhs, params, v, done)
+        return it(coef, krhs, v, done)
 
     v, _ = jax.lax.fori_loop(0, iters, body,
-                             (v0, jnp.zeros((B,), bool)))
-    return v
+                             (v, jnp.zeros((B,), bool)))
+    return jnp.stack(v, axis=1)
 
 
 def _gather_safe(x, idx):
@@ -375,7 +499,7 @@ def fixed_point_adjoint(spec: FusedSpec, pre, Krhs, params, v_star, v_bar):
 
         M^-T = I - Vm^T D^T A^-T KU^T,        A = I + D S,
 
-    where A is the identical (B, k, k) matrix `make_fused_iter` builds
+    where A is the (k, k) matrix `make_fused_iter` builds lane-wise
     (assembled here at v*), and theta_bar = -(dF/dtheta)^T lam is one
     VJP of `residual` at the root. Returns (pre_bar, Krhs_bar,
     params_bar). The v0 cotangent is zero — the root does not depend on
@@ -407,7 +531,9 @@ def fixed_point_adjoint(spec: FusedSpec, pre, Krhs, params, v_star, v_bar):
         A = jnp.eye(k, dtype=cdt)[None] + DS
         # lam = vbar - Vm^T D^T (A^T)^-1 KU^T vbar
         y = jnp.einsum("bnk,bn->bk", pre["KU"].astype(cdt), vb_c)
-        u = _solve_small(jnp.swapaxes(A, 1, 2), y, n_dev)
+        u = jnp.stack(_solve_small(
+            [[A[:, j, i] for j in range(k)] for i in range(k)],
+            [y[:, i] for i in range(k)], n_dev), axis=1)
         u3 = u.reshape(B, n_dev, 3)           # rows (a, b, g) of Um cols
         sau = u3[:, :, 0] - u3[:, :, 1]                        # s_a . u
         sgu = u3[:, :, 2] - 0.5 * (u3[:, :, 0] + u3[:, :, 1])  # s_g . u

@@ -8,10 +8,14 @@ under vmap the batch dimension folds back into the kernel's grid via
 jax's batching rule for pallas_call.
 
 Fused Newton (`fused_newton_step`): the sparse-Newton engine's
-whole-timestep solve (newton.py / fused.py). Backend dispatch: the
-native Pallas kernel on TPU, the identical-result XLA while_loop on
-CPU (interpret-mode Pallas is an emulation — orders of magnitude slower
-than compiled XLA, so it is reserved for the parity tests).
+whole-timestep solve (newton.py / fused.py). Engine choice follows the
+platform the program is LOWERED for (`jax.lax.platform_dependent`), not
+the process's default backend: an f32 spec runs the native Pallas
+kernel on TPU; f64 and mixed specs run the XLA while_loop there
+(Mosaic has no 64-bit types); every other platform runs the
+identical-result XLA while_loop (interpret-mode Pallas is an emulation
+— orders of magnitude slower than compiled XLA, so it is reserved for
+the parity tests). `fused_engine` names the choice.
 
 The dispatching solve is wrapped in a `jax.custom_vjp`: neither the
 while_loop fallback nor the Pallas kernel is reverse-differentiable,
@@ -38,15 +42,30 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def fused_engine(spec, platform: str) -> str:
+    """The engine `fused_newton_step` runs for `spec` on `platform`:
+    "pallas" (the Mosaic kernel — TPU, f32 compute) or "xla_while"."""
+    kernel_ok = spec.dtypes[1] == jnp.float32 and spec.n_dev > 0
+    return "pallas" if platform == "tpu" and kernel_ok else "xla_while"
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
 def _fused_solve(spec, iters, tol, pre, Krhs, params, v0):
-    """Differentiable fused Newton solve (backend-dispatching primal).
+    """Differentiable fused Newton solve (platform-dispatching primal).
     spec/iters/tol are static (FusedSpec hashes by identity)."""
-    if jax.default_backend() == "tpu":
+    def xla(pre, Krhs, params, v0):
+        return _newton.newton_solve(spec, pre, Krhs, params, v0,
+                                    iters, tol)[0]
+
+    if fused_engine(spec, "tpu") != "pallas":
+        return xla(pre, Krhs, params, v0)
+
+    def tpu(pre, Krhs, params, v0):
         return _fused_kernel(spec, pre, Krhs, params, v0,
-                             iters=iters, tol=tol, interpret=False)
-    v, _ = _newton.newton_solve(spec, pre, Krhs, params, v0, iters, tol)
-    return v
+                             iters=iters, tol=tol)
+
+    return jax.lax.platform_dependent(pre, Krhs, params, v0,
+                                      tpu=tpu, default=xla)
 
 
 def _fused_solve_fwd(spec, iters, tol, pre, Krhs, params, v0):
@@ -64,17 +83,11 @@ def _fused_solve_bwd(spec, iters, tol, res, v_bar):
 _fused_solve.defvjp(_fused_solve_fwd, _fused_solve_bwd)
 
 
-def fused_newton_step(spec, pre, Krhs, params, v0, *, iters, tol,
-                      force_kernel: bool = False):
-    """One timestep's fused Newton solve -> v (B, n). Routes to the
-    Pallas kernel on TPU (or when forced, in interpret mode — the parity
-    tests), else to the bit-identical XLA while_loop fallback. Except on
-    the forced-interpret parity path, the result carries the
-    implicit-function VJP, so whole characterizations built on this step
-    are reverse-differentiable."""
-    if force_kernel and jax.default_backend() != "tpu":
-        return _fused_kernel(spec, pre, Krhs, params, v0,
-                             iters=iters, tol=tol, interpret=True)
+def fused_newton_step(spec, pre, Krhs, params, v0, *, iters, tol):
+    """One timestep's fused Newton solve -> v (B, n), through the engine
+    `fused_engine` names for the platform the program is lowered for.
+    The result carries the implicit-function VJP, so whole
+    characterizations built on this step are reverse-differentiable."""
     return _fused_solve(spec, iters, tol, pre, Krhs, params, v0)
 
 

@@ -98,7 +98,7 @@ def parse_query(spec: dict, tech=SYN40) -> Query:
     if kind == "compile":
         return CompileQuery(_parse_cfg(spec.get("cfg", {}), tech),
                             simulate=bool(spec.get("simulate", False)),
-                            solver=spec.get("solver", "jnp"))
+                            solver=spec.get("solver", "pallas"))
     if kind == "match":
         return MatchQuery(
             tuple(_parse_demand(d) for d in spec.get("demands", ())),
@@ -328,6 +328,8 @@ def main(argv=None) -> int:
                          "store directory (run N services against one "
                          "store without duplicating work)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     session = Session(store=args.store, leases=args.leases or None)
     svc = CompileService(session=session, wave_size=args.wave_size)
     src = sys.stdin if args.input == "-" else open(args.input)

@@ -62,7 +62,7 @@ import sys
 import time
 from collections import Counter
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 from repro.core.techfile import SYN40
 
@@ -98,7 +98,8 @@ def worker_main(spool: str, wid: str, store_dir: str,
     """One fleet worker: scan the inbox shard, drain each batch as one
     `CompileService` admission wave against the SHARED leased store,
     publish responses atomically to the outbox. Exits on the spool's
-    `stop` flag."""
+    `stop` flag; its stats file names the platform it computed on."""
+    import jax
     from repro.api import Session
     from repro.api.leases import LeaseManager
     from repro.api.store import ArtifactStore
@@ -160,7 +161,8 @@ def worker_main(spool: str, wid: str, store_dir: str,
             except OSError:
                 pass
     _atomic_json(os.path.join(stats_dir, f"{wid}.json"), {
-        "worker": wid, "service": svc.stats(), "leases": leases.stats(),
+        "worker": wid, "platform": jax.devices()[0].platform,
+        "service": svc.stats(), "leases": leases.stats(),
         "faults": dict(injector.counts) if injector is not None else {}})
     return 0
 
@@ -197,7 +199,13 @@ class Fleet:
     `run(requests)` returns one response per request, in request order,
     every one resolved — success, deterministic error, or structured
     quarantine. Use as a context manager (`with Fleet(...) as f:`) so
-    workers are always stopped and their stats collected."""
+    workers are always stopped and their stats collected.
+
+    A chip belongs to one process at a time, so every worker runs with
+    `JAX_PLATFORMS=cpu` unless its id is in `chip_workers`: N workers
+    racing for one chip would leave all but the first on the CPU
+    without saying so. The dispatcher itself must not hold the chip
+    while a chip worker runs."""
 
     def __init__(self, spool: str, store: Optional[str],
                  n_workers: int = 2, wave_size: int = 16,
@@ -205,7 +213,8 @@ class Fleet:
                  backoff_s: float = 0.25, lease_ttl_s: float = 5.0,
                  poll_s: float = 0.02,
                  fault_specs: Optional[Dict[str, str]] = None,
-                 python: Optional[str] = None, tech=SYN40):
+                 python: Optional[str] = None, tech=SYN40,
+                 chip_workers: Sequence[str] = ()):
         self.spool = os.fspath(spool)
         self.store_dir = os.fspath(store) if store is not None else None
         self.n_workers = int(n_workers)
@@ -216,6 +225,7 @@ class Fleet:
         self.lease_ttl_s = float(lease_ttl_s)
         self.poll_s = float(poll_s)
         self.fault_specs = dict(fault_specs or {})
+        self.chip_workers = frozenset(chip_workers)
         self.python = python or sys.executable
         self.tech = tech
         self.workers: List[_Worker] = []
@@ -252,10 +262,12 @@ class Fleet:
             if spec:
                 cmd += ["--faults", spec]
             w = _Worker(wid, inbox)
+            w_env = env if wid in self.chip_workers \
+                else dict(env, JAX_PLATFORMS="cpu")
             try:
                 log = open(os.path.join(logs, f"{wid}.log"), "w")
                 w.proc = subprocess.Popen(
-                    cmd, env=env, stdout=log, stderr=log,
+                    cmd, env=w_env, stdout=log, stderr=log,
                     stdin=subprocess.DEVNULL)
                 w.alive = True
             except OSError as e:
@@ -553,6 +565,8 @@ def main(argv=None) -> int:
     if args.worker:
         if not args.store:
             ap.error("--worker requires --store")
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
         return worker_main(args.spool, args.worker_id, args.store,
                            wave_size=args.wave_size,
                            lease_ttl_s=args.lease_ttl,

@@ -11,12 +11,21 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """`jax.make_mesh` with every axis Auto-typed: the model code relies
+    on sharding propagation (jnp.take under `with mesh:`), which Explicit
+    axes — what `make_mesh` builds by default on JAX 0.9 — reject."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2, pod: int = 1):
@@ -26,8 +35,8 @@ def make_test_mesh(data: int = 2, model: int = 2, pod: int = 1):
     if n < need:
         raise RuntimeError(f"need {need} devices, have {n}")
     if pod > 1:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return auto_mesh((pod, data, model), ("pod", "data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 def data_axis_names(mesh) -> tuple:

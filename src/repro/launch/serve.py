@@ -39,10 +39,12 @@ def main():
     import jax
     import numpy as np
     from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.model import Model
     from repro.serving import ServeEngine
     from repro.serving.engine import Request
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = dataclasses.replace(cfg.reduced(), name=cfg.name,

@@ -18,13 +18,13 @@ import os
 
 
 def parse_mesh(spec: str):
-    import jax
+    from repro.launch.mesh import auto_mesh
     dims = tuple(int(x) for x in spec.split("x"))
     if len(dims) == 3:
-        return jax.make_mesh(dims, ("pod", "data", "model"))
+        return auto_mesh(dims, ("pod", "data", "model"))
     if len(dims) == 2:
-        return jax.make_mesh(dims, ("data", "model"))
-    return jax.make_mesh(dims, ("data",))
+        return auto_mesh(dims, ("data", "model"))
+    return auto_mesh(dims, ("data",))
 
 
 def main():
@@ -58,7 +58,7 @@ def main():
         shape = ShapeConfig(shape.name, min(shape.seq_len, 128),
                             min(shape.global_batch, 8), shape.kind)
     mesh = parse_mesh(args.mesh) if args.mesh else \
-        jax.make_mesh((len(jax.devices()),), ("data",))
+        parse_mesh(str(len(jax.devices())))
 
     tr = Trainer(cfg, mesh, shape,
                  TrainConfig(total_steps=args.steps,

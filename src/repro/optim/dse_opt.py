@@ -196,7 +196,7 @@ def optimize(cfg: BankConfig, *, target_freq_hz: float,
     targs = dict(target_freq_hz=target_freq_hz, target_ret_s=target_ret_s,
                  allow_refresh=allow_refresh, num_words=num_words)
 
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     with enable_x64():
         seed, seed_obj, seed_met, n_grid = grid_seed(
             cfg, seed_vdd_scales, objective=objective, lat=seed_lattice,

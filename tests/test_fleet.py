@@ -285,6 +285,32 @@ def test_fleet_rejects_invalid_query_without_retry(tmp_path):
     assert "quarantined" not in resp[0]  # deterministic error, no retry
 
 
+def test_fleet_workers_stay_off_the_chip_unless_given_it(tmp_path,
+                                                         monkeypatch):
+    """One chip, one process: a worker runs with JAX_PLATFORMS=cpu unless
+    the caller names it in `chip_workers`."""
+    from repro.launch import fleet as fleet_mod
+    envs = {}
+
+    class Proc:
+        def __init__(self, cmd, env, **_):
+            envs[cmd[cmd.index("--worker-id") + 1]] = env
+
+        def poll(self):
+            return 0
+
+        def wait(self, timeout=None):
+            return 0
+
+    monkeypatch.setattr(fleet_mod.subprocess, "Popen", Proc)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with Fleet(str(tmp_path / "spool"), None, n_workers=2,
+               chip_workers=("w1",)):
+        pass
+    assert envs["w0"]["JAX_PLATFORMS"] == "cpu"
+    assert envs["w1"]["JAX_PLATFORMS"] == "tpu"
+
+
 def test_fleet_survives_worker_killed_mid_wave(tmp_path):
     spool, store = str(tmp_path / "spool"), str(tmp_path / "store")
     reqs = [_tiny_spec(nw, f"r{i}", f"t{i % 2}")
@@ -299,6 +325,7 @@ def test_fleet_survives_worker_killed_mid_wave(tmp_path):
         stats = fleet.stats()
     assert all(r["ok"] for r in resp)
     assert stats["worker_deaths"] == 1
+    assert stats["workers"]["w1"]["platform"] == "cpu"
     assert stats["retries"] >= 1
     assert LeaseManager.duplicate_evals(store) == {}
     for r in resp:          # bit-identical to the in-process baseline

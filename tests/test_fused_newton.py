@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core import timing
 from repro.core.bank import BankConfig, build_bank
@@ -113,10 +113,10 @@ def test_fused_rejects_unknown_override_batches():
 # Pallas kernel (interpret mode) == XLA fallback, per precision
 # ---------------------------------------------------------------------------
 
-def _step_operands(precision):
+def _step_operands(precision, B=3):
     """Physically consistent single-timestep operands for the fused
     solve: first backward-Euler step of the read transient."""
-    system, inp = _lattice_inputs()
+    system, inp = _lattice_inputs(B=B)
     spec = nwt.build_fused_spec(system, precision)
     sdt, cdt = spec.dtypes
     B = inp["t_end"].shape[0]
@@ -132,15 +132,31 @@ def _step_operands(precision):
     return spec, pre, Krhs, params, v0
 
 
-@pytest.mark.parametrize("precision", ["f64", "f32"])
-def test_fused_kernel_interpret_matches_xla(precision):
+@pytest.mark.parametrize("precision,platform,engine", [
+    ("f32", "tpu", "pallas"), ("f64", "tpu", "xla_while"),
+    ("mixed", "tpu", "xla_while"), ("f32", "cpu", "xla_while")])
+def test_fused_engine_follows_platform_and_compute_dtype(precision, platform,
+                                                         engine):
+    """Mosaic has no 64-bit types: only f32-compute specs take the kernel,
+    and only on TPU."""
     with enable_x64():
-        spec, pre, Krhs, params, v0 = _step_operands(precision)
+        system, _ = _lattice_inputs()
+    spec = nwt.build_fused_spec(system, precision)
+    assert solve_ops.fused_engine(spec, platform) == engine
+
+
+@pytest.mark.parametrize("B", [3, 260])
+@pytest.mark.parametrize("precision", ["f64", "f32"])
+def test_fused_kernel_interpret_matches_xla(precision, B):
+    """B=260 spans three 128-lane tile rows, one per grid step, with the
+    last one edge-padded."""
+    with enable_x64():
+        spec, pre, Krhs, params, v0 = _step_operands(precision, B)
         v_xla, _ = nwt.newton_solve(spec, pre, Krhs, params, v0, 6, 1e-9)
         v_fix = nwt.newton_solve_fixed(spec, pre, Krhs, params, v0,
                                        6, 1e-9)
         v_ker = fused_newton(spec, pre, Krhs, params, v0, iters=6,
-                             tol=1e-9, block_b=4, interpret=True)
+                             tol=1e-9, block_rows=1, interpret=True)
         # per-lane freeze: early-exit while_loop == fixed fori_loop
         np.testing.assert_array_equal(np.asarray(v_xla), np.asarray(v_fix))
         np.testing.assert_array_equal(np.asarray(v_ker), np.asarray(v_fix))
@@ -148,16 +164,15 @@ def test_fused_kernel_interpret_matches_xla(precision):
 
 
 def test_fused_kernel_dispatcher_routes_and_pads():
-    """ops.fused_newton_step(force_kernel=True) runs the interpret-mode
-    kernel (incl. batch padding: B=3 pads to block_b=8) and matches the
-    XLA fallback it would otherwise take on CPU."""
+    """On CPU ops.fused_newton_step routes to the XLA while_loop, which
+    matches the interpret-mode kernel at its default tiling (lane
+    padding: B=3 pads to one 128-lane row)."""
     with enable_x64():
         spec, pre, Krhs, params, v0 = _step_operands("f64")
         v_fb = solve_ops.fused_newton_step(spec, pre, Krhs, params, v0,
                                            iters=6, tol=1e-9)
-        v_ker = solve_ops.fused_newton_step(spec, pre, Krhs, params, v0,
-                                            iters=6, tol=1e-9,
-                                            force_kernel=True)
+        v_ker = fused_newton(spec, pre, Krhs, params, v0, iters=6,
+                             tol=1e-9, interpret=True)
         np.testing.assert_array_equal(np.asarray(v_ker), np.asarray(v_fb))
 
 

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
+from jax import enable_x64
 from jax.test_util import check_grads
 
 from repro.core import dse

@@ -1,6 +1,7 @@
 """Launch-layer integration: build->lower->compile->analyze on a small
 mesh, HLO analyzer invariants, sharding rule table, report rendering."""
 import dataclasses
+import os
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +27,25 @@ def _mesh():
         pytest.skip("needs >= 2 host devices (run under "
                     "XLA_FLAGS=--xla_force_host_platform_device_count=8)")
     return make_test_mesh(data=2, model=n // 2 if n < 8 else 4)
+
+
+def test_compile_cache_dir_from_env_else_fixed_in_checkout(tmp_path,
+                                                         monkeypatch):
+    """JAX reads JAX_COMPILATION_CACHE_DIR itself; without it the cache
+    goes to one fixed directory inside the checkout."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    try:
+        path = compile_cache.enable_compile_cache()
+        assert path == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 @pytest.mark.parametrize("kind", ["train", "prefill", "decode"])

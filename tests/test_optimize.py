@@ -13,7 +13,7 @@ The contract under test (dse_opt.optimize):
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.api.queries import OptimizeQuery
 from repro.core import dse

@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.api import CalibratedTable, DesignTable, Session, SweepQuery
 from repro.core import timing
@@ -127,6 +127,41 @@ def test_batched_characterization_matches_scalar_3_topologies():
         else:
             assert ch.t_cell_s == pytest.approx(t_ref, rel=0.01), cfg
         assert ch.t_cell_analytic_s > 0 and ch.n_steps == 200
+
+
+def test_groups_of_one_structure_share_a_program_with_own_values():
+    """Write-Vt flavor, WWL boost and the cell's read device change element
+    and device VALUES, not the read column's structure: those topology
+    groups run one shared fused program, and each still gets its own
+    values (the OS-read gc2t_osos would drift from its dense reference if
+    it inherited gc2t_nn's devices)."""
+    from repro.core.dse_batch import topology_key
+    from repro.core.spice import char_batch
+    cfgs = [BankConfig(16, 16, "gc2t_nn", write_vt=vt, wwlls=ls)
+            for vt in (None, "nmos_hvt") for ls in (False, True)]
+    cfgs.append(BankConfig(16, 16, "gc2t_osos"))
+    chars = characterize(cfgs, n_steps=100)
+    refs = characterize(cfgs, n_steps=100, solver="jnp")
+    for cfg, ch, ref in zip(cfgs, chars, refs):
+        assert ch.swing_ok == ref.swing_ok
+        assert ch.t_cell_s == pytest.approx(ref.t_cell_s, rel=0.01), cfg
+    with enable_x64():
+        trs = {id(char_batch._pipeline(
+            build_bank(c), topology_key(c) + (8, 100, "pallas", "f64"))[1])
+            for c in cfgs}
+    assert len(trs) == 1
+
+
+def test_compile_simulate_is_a_one_lane_lattice():
+    """CompileQuery(simulate=True) characterizes through the lattice
+    engine (one lane), not the dense scalar stepper it is checked
+    against."""
+    cfg = BankConfig(16, 16, "gc2t_nn")
+    rep = Session().compile(cfg, simulate=True)
+    (ch,) = characterize([cfg])
+    assert rep.t_cell_sim_s == ch.t_cell_s
+    t_ref, _ = timing.simulate_read(build_bank(cfg))
+    assert rep.t_cell_sim_s == pytest.approx(t_ref, rel=0.01)
 
 
 def test_characterize_skips_non_gain_cells():
