@@ -51,6 +51,7 @@ from repro.api.plan import Node
 from repro.core import compiler as compiler_mod
 from repro.core import dse
 from repro.core import dse_batch
+from repro.core import trace
 from repro.core.spice import char_batch
 
 __all__ = ["Executor", "QueryFuture"]
@@ -172,7 +173,9 @@ class Executor:
                     self.stats["result_cache_hits"] += 1
                     fut._set(result=cached)
                     continue
-                jobs.append((query, fut, plan_mod.plan_query(s, query)))
+                with trace.span("api.plan"):
+                    p = plan_mod.plan_query(s, query)
+                jobs.append((query, fut, p))
             except Exception as e:                       # noqa: BLE001
                 fut._set(error=e)
         if not jobs:
@@ -192,17 +195,20 @@ class Executor:
 
         out: Dict[str, object] = {}
         err: Dict[str, BaseException] = {}
-        self._coalesce_points([n for n in nodes.values()
-                               if n.kind == "points"], err)
-        self._coalesce_transient([n for n in nodes.values()
-                                  if n.kind == "transient"], err)
-        for n in nodes.values():
-            if n.key in err:
-                continue
-            try:
-                out[n.key] = self._exec_node(n, out, err)
-            except Exception as e:                       # noqa: BLE001
-                err[n.key] = e
+        with trace.span("api.execute") as sp:
+            if sp is not None:
+                sp.attrs["kinds"] = sorted({n.kind for n in nodes.values()})
+            self._coalesce_points([n for n in nodes.values()
+                                   if n.kind == "points"], err)
+            self._coalesce_transient([n for n in nodes.values()
+                                      if n.kind == "transient"], err)
+            for n in nodes.values():
+                if n.key in err:
+                    continue
+                try:
+                    out[n.key] = self._exec_node(n, out, err)
+                except Exception as e:                   # noqa: BLE001
+                    err[n.key] = e
 
         for query, fut, p in jobs:
             try:
@@ -218,7 +224,8 @@ class Executor:
                            None)
                 if bad is not None:
                     raise bad
-                res = p.compose(s, out)
+                with trace.span("api.compose"):
+                    res = p.compose(s, out)
                 s._result_cache_put(query, res)
                 fut._set(result=res)
             except Exception as e:                       # noqa: BLE001

@@ -52,6 +52,7 @@ from repro.api.store import ArtifactStore
 from repro.api import plan as plan_mod
 from repro.core import dse
 from repro.core import multibank as mb_mod
+from repro.core import trace
 from repro.core.bank import BankConfig
 from repro.core.dse import Demand, DesignPoint
 from repro.core.dse_batch import VddLattice
@@ -108,13 +109,14 @@ class Session:
         plan -> (coalescing) execute -> compose; a Query subclass
         overriding run(session) — even a subclass of a built-in query —
         keeps its legacy eager hook."""
-        if type(query).run is not Query.run:
-            return query.run(self)         # legacy subclass hook
-        if not plan_mod.plannable(query):
-            raise TypeError(
-                f"cannot plan query of type {type(query).__name__} and "
-                "it overrides no run(session) hook")
-        return self._executor.run_one(query)
+        with trace.request("api.run"):
+            if type(query).run is not Query.run:
+                return query.run(self)         # legacy subclass hook
+            if not plan_mod.plannable(query):
+                raise TypeError(
+                    f"cannot plan query of type {type(query).__name__} "
+                    "and it overrides no run(session) hook")
+            return self._executor.run_one(query)
 
     def submit(self, query: Query) -> QueryFuture:
         """Queue a query; returns a Future. Queued queries drain in one

@@ -45,6 +45,7 @@ from jax import enable_x64
 from repro.core import bank as bank_mod
 from repro.core import retention as ret_mod
 from repro.core import timing as timing_mod
+from repro.core import trace
 from repro.core.bank import BankConfig, build_bank
 from repro.core.dse import DesignPoint
 from repro.core.host import on_host
@@ -101,7 +102,7 @@ def _group_constants(cfg0: BankConfig, bank0, vdd_scale: float = 1.0) -> dict:
     """Electricals that depend only on (cell topology, operating voltage)
     — computed with the same scalar calls the reference `dse.evaluate`
     path makes at that vdd_scale, on the host CPU device like it."""
-    with on_host():
+    with trace.span("dse_batch.group_constants"), on_host():
         return _group_constants_host(cfg0, bank0, vdd_scale)
 
 
@@ -109,31 +110,36 @@ def _group_constants_host(cfg0: BankConfig, bank0, vdd_scale: float) -> dict:
     tech = with_vdd_scale(cfg0.tech, vdd_scale)
     cell = bank0.cell
     if bank0.is_gc:
-        bit = 0 if cell.read_on_sn_low else 1
-        v_sn = cell.v_sn_written(tech, bit, wwlls=cfg0.wwlls,
-                                 wwl_boost=cfg0.wwl_boost)
-        v_rbl0 = 0.0 if cell.predischarge else tech.vdd
-        swing = tech.v_sense_se
-        v_rbl_mid = v_rbl0 + (0.5 * swing if cell.predischarge
-                              else -0.5 * swing)
-        i_cell = cell.i_read(tech, v_sn, v_rbl_mid)
-        off_sn = cell.v_sn_written(tech, 1 if cell.read_on_sn_low else 0)
-        i_leak1 = cell.i_leak_rbl(tech, off_sn)
-        t_ret = ret_mod.analyze(cell, tech, wwlls=cfg0.wwlls,
-                                wwl_boost=cfg0.wwl_boost).t_ret_s
-        wf = cell.wf(tech)
-        v_gate = tech.vdd + (cfg0.wwl_boost if cfg0.wwlls else 0.0)
-        i_on = abs(float(dv.channel_current(
-            wf, cell.w_write, cell.l_write, v_gate, tech.vdd,
-            tech.vdd * 0.45)))
+        with trace.span("dse_batch.currents"):
+            bit = 0 if cell.read_on_sn_low else 1
+            v_sn = cell.v_sn_written(tech, bit, wwlls=cfg0.wwlls,
+                                     wwl_boost=cfg0.wwl_boost)
+            v_rbl0 = 0.0 if cell.predischarge else tech.vdd
+            swing = tech.v_sense_se
+            v_rbl_mid = v_rbl0 + (0.5 * swing if cell.predischarge
+                                  else -0.5 * swing)
+            i_cell = cell.i_read(tech, v_sn, v_rbl_mid)
+            off_sn = cell.v_sn_written(tech, 1 if cell.read_on_sn_low else 0)
+            i_leak1 = cell.i_leak_rbl(tech, off_sn)
+        with trace.span("dse_batch.retention"):
+            t_ret = ret_mod.analyze(cell, tech, wwlls=cfg0.wwlls,
+                                    wwl_boost=cfg0.wwl_boost).t_ret_s
+        with trace.span("dse_batch.currents"):
+            wf = cell.wf(tech)
+            v_gate = tech.vdd + (cfg0.wwl_boost if cfg0.wwlls else 0.0)
+            i_on = abs(float(dv.channel_current(
+                wf, cell.w_write, cell.l_write, v_gate, tech.vdd,
+                tech.vdd * 0.45)))
         return dict(i_cell=i_cell, i_leak1=i_leak1, dv_sense=swing,
                     t_ret=t_ret, vdd=tech.vdd,
                     t_sn=cell.sn_cap(tech) * 0.9 * tech.vdd
                     / max(i_on, 1e-12),
                     cell_leak_per_bit=0.0)
-    return dict(i_cell=cell.i_read(tech), i_leak1=0.0,
+    with trace.span("dse_batch.currents"):
+        i_cell, leak = cell.i_read(tech), cell.cell_leakage(tech)
+    return dict(i_cell=i_cell, i_leak1=0.0,
                 dv_sense=tech.v_sense_diff, t_ret=float("inf"), t_sn=0.0,
-                vdd=tech.vdd, cell_leak_per_bit=cell.cell_leakage(tech))
+                vdd=tech.vdd, cell_leak_per_bit=leak)
 
 
 # deterministic pure functions of (cell topology, deck, operating
@@ -360,20 +366,23 @@ def evaluate_vdd_lattice(cfgs: Sequence[BankConfig],
                refresh_w=z(), e_read_j=z(), e_write_j=z())
     area = np.zeros(P); bits = np.zeros(P); nw = np.zeros(P)
     is_gc = np.zeros(P, bool)
-    for idx in group_by_topology(cfgs).values():
-        sub = [cfgs[i] for i in idx]
-        banks = [build_bank(c) for c in sub]
-        a = _eval_group_arrays(sub, banks, vdd_scales)
-        cols = np.asarray(idx)
-        for dst, src in (("f_max_hz", "f"), ("t_read_s", "t_read"),
-                         ("t_write_s", "t_write"), ("leakage_w", "leakage"),
-                         ("refresh_w", "refresh"), ("e_read_j", "e_read"),
-                         ("e_write_j", "e_write"), ("swing_ok", "swing_ok")):
-            out[dst][:, cols] = a[src]
-        out["retention_s"][:, cols] = a["t_ret"][:, None]
-        area[cols], bits[cols], nw[cols] = a["area"], a["bits"], \
-            a["num_words"]
-        is_gc[cols] = banks[0].is_gc
+    with trace.span("dse_batch.lattice"):
+        for idx in group_by_topology(cfgs).values():
+            sub = [cfgs[i] for i in idx]
+            banks = [build_bank(c) for c in sub]
+            a = _eval_group_arrays(sub, banks, vdd_scales)
+            cols = np.asarray(idx)
+            for dst, src in (("f_max_hz", "f"), ("t_read_s", "t_read"),
+                             ("t_write_s", "t_write"),
+                             ("leakage_w", "leakage"),
+                             ("refresh_w", "refresh"), ("e_read_j", "e_read"),
+                             ("e_write_j", "e_write"),
+                             ("swing_ok", "swing_ok")):
+                out[dst][:, cols] = a[src]
+            out["retention_s"][:, cols] = a["t_ret"][:, None]
+            area[cols], bits[cols], nw[cols] = a["area"], a["bits"], \
+                a["num_words"]
+            is_gc[cols] = banks[0].is_gc
     return VddLattice(cfgs, vdd_scales, out["f_max_hz"], out["t_read_s"],
                       out["t_write_s"], out["retention_s"], out["swing_ok"],
                       out["leakage_w"], out["refresh_w"], out["e_read_j"],

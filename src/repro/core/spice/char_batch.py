@@ -58,6 +58,7 @@ import numpy as np
 from jax import enable_x64
 
 from repro.core import timing as timing_mod
+from repro.core import trace
 from repro.core.bank import BankConfig, build_bank
 from repro.core.host import on_host
 from repro.core.dse_batch import (group_by_topology, pad_bucket,
@@ -158,103 +159,117 @@ def _pipeline(bank0, key: tuple):
     return out
 
 
-def _characterize_group(cfgs: List[BankConfig], banks, *, n_seg: int,
+def _characterize_group(cfgs: List[BankConfig], *, n_seg: int,
                         n_steps: int, solver: str,
                         precision: str = "f64",
-                        parasitics: str = "modeled") -> List[TransientChar]:
-    bank0 = banks[0]
-    tech = cfgs[0].tech
-    cell = bank0.cell
-    key = topology_key(cfgs[0]) + (n_seg, n_steps, solver, precision)
-    system, tr, res_stamps, cap_stamps, src_G, meta = _pipeline(bank0, key)
+                        parasitics: str = "modeled"
+                        ) -> Optional[List[TransientChar]]:
+    """One topology group, None where its cell has no single-ended read
+    column. Spans: host prep, dispatch of the device work, the host
+    blocked on its first result, result assembly."""
+    with trace.span("char_batch.prep"):
+        banks = [build_bank(c) for c in cfgs]
+        if not banks[0].is_gc:
+            return None
+        bank0 = banks[0]
+        tech = cfgs[0].tech
+        cell = bank0.cell
+        key = topology_key(cfgs[0]) + (n_seg, n_steps, solver, precision)
+        system, tr, res_stamps, cap_stamps, src_G, meta = _pipeline(bank0, key)
 
-    # parasitics="extracted" (the layout tier): ONE batched extraction
-    # over the group replaces the hand-modeled bitline ladder totals.
-    # Via R/C folds uniformly into the n_seg segments, so the element
-    # structure — and with it the compiled pipeline — is unchanged.
-    ext = None
-    if parasitics == "extracted":
-        from repro.geom import extract as geom_extract
-        ext = geom_extract.extract_lattice(banks)
+        # parasitics="extracted" (the layout tier): ONE batched extraction
+        # over the group replaces the hand-modeled bitline ladder totals.
+        # Via R/C folds uniformly into the n_seg segments, so the element
+        # structure — and with it the compiled pipeline — is unchanged.
+        ext = None
+        if parasitics == "extracted":
+            from repro.geom import extract as geom_extract
+            ext = geom_extract.extract_lattice(banks)
 
-    # -- lift structural values into per-point parameter arrays. The
-    # per-point netlist builder is the single source of truth for element
-    # VALUES (ladder R/C, device caps, SA load); structure is asserted
-    # identical to the template.
-    g_vals = np.zeros((len(banks), len(res_stamps)))
-    c_vals = np.zeros((len(banks), len(cap_stamps)))
-    t_an = np.zeros((len(banks),))
-    for p, bank in enumerate(banks):
-        rc_p = (float(ext["bl_r_ohm"][p]), float(ext["bl_c_f"][p])) \
-            if ext is not None else None
-        with on_host():
-            ckt_p, _ = timing_mod.read_netlist(bank, n_seg=n_seg, rc=rc_p)
-            t_an[p] = timing_mod.cell_read_time(bank, rc=rc_p)[0]
-        assert len(ckt_p.names) == len(system.names) and \
-            len(ckt_p.res) == len(res_stamps) and \
-            len(ckt_p.caps) == len(cap_stamps), "topology group mismatch"
-        g_vals[p] = [g for _, _, g in ckt_p.res]
-        c_vals[p] = [c for _, _, c in ckt_p.caps]
+        # -- lift structural values into per-point parameter arrays. The
+        # per-point netlist builder is the single source of truth for element
+        # VALUES (ladder R/C, device caps, SA load); structure is asserted
+        # identical to the template.
+        g_vals = np.zeros((len(banks), len(res_stamps)))
+        c_vals = np.zeros((len(banks), len(cap_stamps)))
+        t_an = np.zeros((len(banks),))
+        for p, bank in enumerate(banks):
+            rc_p = (float(ext["bl_r_ohm"][p]), float(ext["bl_c_f"][p])) \
+                if ext is not None else None
+            with on_host():
+                ckt_p, _ = timing_mod.read_netlist(bank, n_seg=n_seg, rc=rc_p)
+                t_an[p] = timing_mod.cell_read_time(bank, rc=rc_p)[0]
+            assert len(ckt_p.names) == len(system.names) and \
+                len(ckt_p.res) == len(res_stamps) and \
+                len(ckt_p.caps) == len(cap_stamps), "topology group mismatch"
+            g_vals[p] = [g for _, _, g in ckt_p.res]
+            c_vals[p] = [c for _, _, c in ckt_p.caps]
 
-    # float64 assembly, float64 all the way down (the group runs under
-    # enable_x64 — see characterize; no f32 cast happens or should)
-    G_b = src_G[None] + np.einsum("br,rij->bij", g_vals, res_stamps)
-    C_b = np.einsum("bc,cij->bij", c_vals, cap_stamps)
+        # float64 assembly, float64 all the way down (the group runs under
+        # enable_x64 — see characterize; no f32 cast happens or should)
+        G_b = src_G[None] + np.einsum("br,rij->bij", g_vals, res_stamps)
+        C_b = np.einsum("bc,cij->bij", c_vals, cap_stamps)
 
-    # -- per-point stop time + waves, from the SAME stimulus recipe as
-    # the scalar simulate_read (timing.read_stimulus), edge-padded to the
-    # longest waveform exactly like Transient.pack_waves
-    t_end = np.maximum(timing_mod.T_END_OVER_ANALYTIC * t_an,
-                       timing_mod.T_END_MIN_S)
-    t0 = timing_mod.T0_FRACTION * t_end
-    B = len(banks)
-    wt = wv = None
-    v_pre = 0.0
-    for p in range(B):
-        with on_host():
-            waves_p, v_pre = timing_mod.read_stimulus(cell, tech,
-                                                      meta["v_sn"], t0[p])
-        if wt is None:   # buffer dims derived from the stimulus itself
-            k = max(len(t) for t, _ in waves_p)
-            wt = np.zeros((B, len(waves_p), k))
-            wv = np.zeros((B, len(waves_p), k))
-        for w, (t, v) in enumerate(waves_p):
-            wt[p, w] = t + [t[-1]] * (k - len(t))
-            wv[p, w] = v + [v[-1]] * (k - len(v))
+        # -- per-point stop time + waves, from the SAME stimulus recipe as
+        # the scalar simulate_read (timing.read_stimulus), edge-padded to the
+        # longest waveform exactly like Transient.pack_waves
+        t_end = np.maximum(timing_mod.T_END_OVER_ANALYTIC * t_an,
+                           timing_mod.T_END_MIN_S)
+        t0 = timing_mod.T0_FRACTION * t_end
+        B = len(banks)
+        wt = wv = None
+        v_pre = 0.0
+        for p in range(B):
+            with on_host():
+                waves_p, v_pre = timing_mod.read_stimulus(cell, tech,
+                                                          meta["v_sn"], t0[p])
+            if wt is None:   # buffer dims derived from the stimulus itself
+                k = max(len(t) for t, _ in waves_p)
+                wt = np.zeros((B, len(waves_p), k))
+                wv = np.zeros((B, len(waves_p), k))
+            for w, (t, v) in enumerate(waves_p):
+                wt[p, w] = t + [t[-1]] * (k - len(t))
+                wv[p, w] = v + [v[-1]] * (k - len(v))
 
-    # pad the batch to a power-of-two bucket (edge-repeat) so the jitted
-    # lattice program is reused across characterizations of different
-    # sizes — vmap shapes are static, and session sweeps routinely hand
-    # this pipeline varying-size "missing" subsets
-    Bp = pow2_bucket(B)
-    if Bp > B:
-        pad = lambda a: pad_bucket(a, Bp)
-        G_b, C_b, wt, wv = map(pad, (G_b, C_b, wt, np.asarray(wv)))
-        t_end_p = pad(t_end)
-    else:
-        t_end_p = t_end
+        # pad the batch to a power-of-two bucket (edge-repeat) so the jitted
+        # lattice program is reused across characterizations of different
+        # sizes — vmap shapes are static, and session sweeps routinely hand
+        # this pipeline varying-size "missing" subsets
+        Bp = pow2_bucket(B)
+        if Bp > B:
+            pad = lambda a: pad_bucket(a, Bp)
+            G_b, C_b, wt, wv = map(pad, (G_b, C_b, wt, np.asarray(wv)))
+            t_end_p = pad(t_end)
+        else:
+            t_end_p = t_end
 
-    over = {"G": G_b, "C": C_b}
-    if solver != "jnp":
-        over.update(_device_batches(system, Bp))
-    res = tr.run_lattice(wt, wv, t_end_p, n_steps, over_batches=over,
-                         v0=jnp.full((system.n,), v_pre))
+        over = {"G": G_b, "C": C_b}
+        if solver != "jnp":
+            over.update(_device_batches(system, Bp))
 
-    swing = tech.v_sense_se
-    target = v_pre + (swing if cell.predischarge else -swing)
-    tc, valid = crossing_time(res["t"], res["rbl_near"], target,
-                              rising=cell.predischarge)
-    tc = np.asarray(tc)[:B]
-    valid = np.asarray(valid)[:B]
-    t_cell = np.where(valid, tc - t0, np.inf)
+    trace.count("char_batch.points", B)
+    trace.count("char_batch.lanes", Bp)
 
-    out = []
-    for p, cfg in enumerate(cfgs):
-        sim = float(t_cell[p])
-        dev = abs(t_an[p] - sim) / sim if np.isfinite(sim) and sim > 0 \
-            else float("inf")
-        out.append(TransientChar(cfg, sim, float(t_an[p]), float(dev),
-                                 bool(valid[p]), float(t_end[p]), n_steps))
+    with trace.span("char_batch.dispatch"):
+        res = tr.run_lattice(wt, wv, t_end_p, n_steps, over_batches=over,
+                             v0=jnp.full((system.n,), v_pre))
+        swing = tech.v_sense_se
+        target = v_pre + (swing if cell.predischarge else -swing)
+        tc, valid = crossing_time(res["t"], res["rbl_near"], target,
+                                  rising=cell.predischarge)
+    with trace.span("char_batch.wait"):
+        tc = np.asarray(tc)[:B]
+        valid = np.asarray(valid)[:B]
+    with trace.span("char_batch.finish"):
+        t_cell = np.where(valid, tc - t0, np.inf)
+        out = []
+        for p, cfg in enumerate(cfgs):
+            sim = float(t_cell[p])
+            dev = abs(t_an[p] - sim) / sim if np.isfinite(sim) and sim > 0 \
+                else float("inf")
+            out.append(TransientChar(cfg, sim, float(t_an[p]), float(dev),
+                                     bool(valid[p]), float(t_end[p]),
+                                     n_steps))
     return out
 
 
@@ -460,13 +475,11 @@ def characterize(cfgs: Sequence[BankConfig], *, n_steps: int = 300,
     with enable_x64():
         for idx in group_by_topology(cfgs).values():
             group = [cfgs[i] for i in idx]
-            banks = [build_bank(c) for c in group]
-            if not banks[0].is_gc:
-                continue
-            chars = _characterize_group(group, banks, n_seg=n_seg,
-                                        n_steps=n_steps, solver=solver,
-                                        precision=precision,
-                                        parasitics=parasitics)
-            for i, ch in zip(idx, chars):
+            with trace.span("char_batch.group"):
+                chars = _characterize_group(group, n_seg=n_seg,
+                                            n_steps=n_steps, solver=solver,
+                                            precision=precision,
+                                            parasitics=parasitics)
+            for i, ch in zip(idx, chars or ()):
                 out[i] = ch
     return out

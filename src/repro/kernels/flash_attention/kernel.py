@@ -1,9 +1,9 @@
 """Pallas TPU kernel: flash attention forward (causal, GQA).
 
-Why this exists (§Perf hillclimb #1, EXPERIMENTS.md): the pure-JAX flash
-path materializes every (cq, ckv) score/probability block in HBM — the
-dominant roofline term for every attention-heavy cell. In this kernel the
-whole online-softmax tile pipeline (scores -> max -> exp -> accumulate)
+Why this exists: the pure-JAX flash path materializes every (cq, ckv)
+score/probability block in HBM — the dominant roofline term for every
+attention-heavy cell. In this kernel the whole online-softmax tile
+pipeline (scores -> max -> exp -> accumulate)
 lives in VMEM; HBM traffic collapses to Q + K + V + O.
 
 Grid: (B, K_heads, nq) — one program per (batch, kv-head, q-block),

@@ -1,10 +1,10 @@
 """Trip-count-aware analysis of post-SPMD optimized HLO text.
 
-Why this exists (EXPERIMENTS.md §Roofline methodology): XLA's built-in
-``compiled.cost_analysis()`` visits each while-loop body ONCE, so any
-program built around lax.scan (scan-over-layers, flash-attention KV scan,
-microbatching) under-counts FLOPs/bytes by the trip count, and it reports
-no per-collective breakdown at all. This module re-derives:
+Why this exists: XLA's built-in ``compiled.cost_analysis()`` visits each
+while-loop body ONCE, so any program built around lax.scan
+(scan-over-layers, flash-attention KV scan, microbatching) under-counts
+FLOPs/bytes by the trip count, and it reports no per-collective
+breakdown at all. This module re-derives:
 
   * flops            - 2*M*N*K for every dot, multiplied through nested
                        while trip counts (parsed from loop conditions)

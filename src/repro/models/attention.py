@@ -170,8 +170,7 @@ def _seqpar_flash(q, k, v, mesh, *, causal, window, block_skip):
     replicated over 'model' (they already are when the head count doesn't
     divide the axis). Each model rank computes its q slice against the
     full KV — zero collectives inside attention; the (9x-measured) win is
-    that per-device score-block HBM traffic drops by the axis size.
-    §Perf hillclimb #1 (EXPERIMENTS.md)."""
+    that per-device score-block HBM traffic drops by the axis size."""
     from jax.sharding import PartitionSpec as P
     from jax.experimental.shard_map import shard_map
 

@@ -1,5 +1,5 @@
 """Launch-layer integration: build->lower->compile->analyze on a small
-mesh, HLO analyzer invariants, sharding rule table, report rendering."""
+mesh, HLO analyzer invariants, sharding rule table."""
 import dataclasses
 import os
 
@@ -110,28 +110,3 @@ def test_hlo_analyzer_trip_counts_and_dots():
     expect = L * 2 * (8 // nd) * d * (d // nm)
     assert an["flops"] == pytest.approx(expect, rel=0.05)
     assert an["dot_count"] == L
-
-
-def test_report_tables(tmp_path):
-    import glob
-    import json
-    from repro.launch import report
-    # synthesize two records
-    rec = {"arch": "a", "shape": "s", "mesh": "16x16", "kind": "train",
-           "compile_s": 1.0,
-           "roofline": {"compute_s": 1, "memory_s": 2, "collective_s": 0.5,
-                        "bottleneck": "memory", "model_flops": 1e12,
-                        "hlo_flops_global": 2e12, "mfu": 0.25,
-                        "step_time_s": 2.0, "roofline_frac": 1.0},
-           "hlo_analysis": {"flops": 1, "mem_bytes": 2,
-                            "collective_wire_bytes": 3,
-                            "collective_by_type": {"all-reduce": 3}},
-           "memory_analysis": {"argument_bytes_per_device": 1,
-                               "temp_bytes_per_device": 2},
-           "peak_bytes_per_device": 3, "fits_16g_hbm": True}
-    with open(tmp_path / "a__s__pod256.json", "w") as f:
-        json.dump(rec, f)
-    recs = report.load(str(tmp_path))
-    t = report.roofline_table(recs)
-    assert "memory" in t and "| a | s |" in t
-    assert "a:s" in report.summary(recs)
