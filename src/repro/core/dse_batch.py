@@ -10,8 +10,9 @@ lattice at once:
      WWL boost, tech) so array shapes stay static per group;
   2. compute the group-constant electricals ONCE per (group, vdd_scale)
      with the SAME scalar calls `dse.evaluate` makes (read/leak currents
-     at the written SN level, the retention integral, the write SN
-     settle);
+     at the written SN level, the write SN settle) — and the retention
+     integral of every missing (group, vdd_scale) in one batched pass of
+     the same eager ops (`retention.t_ret_rows`);
   3. `jax.vmap` the per-point analytic timing + power algebra across the
      group's struct-of-arrays (rows, wire RC, word size, ...) in float64
      (jax.enable_x64), reusing the formula kernels from
@@ -20,8 +21,8 @@ lattice at once:
      RC are voltage-independent, so the structural arrays are shared
      across the whole voltage ladder).
 
-Because the group constants come from the identical scalar calls and the
-per-point algebra is the identical float64 expression tree, batched
+Because the group constants come from the identical scalar calls (and
+eager ops, for retention) and the per-point algebra is the identical float64 expression tree, batched
 results match `dse.evaluate` bit-for-bit — asserted in
 tests/test_api.py, tests/test_codesign.py and benchmarks.
 
@@ -98,15 +99,38 @@ def evaluate_batch(cfgs: Sequence[BankConfig],
     return [lat.point(0, i) for i in range(len(lat.cfgs))]
 
 
-def _group_constants(cfg0: BankConfig, bank0, vdd_scale: float = 1.0) -> dict:
+def _group_constants(cfg0: BankConfig, bank0, vdd_scale: float = 1.0, *,
+                     t_ret: Optional[float] = None) -> dict:
     """Electricals that depend only on (cell topology, operating voltage)
     — computed with the same scalar calls the reference `dse.evaluate`
-    path makes at that vdd_scale, on the host CPU device like it."""
+    path makes at that vdd_scale, on the host CPU device like it. A
+    gain cell's retention is `t_ret` where the caller batched it
+    (`_retention`), else a one-row batch here."""
     with trace.span("dse_batch.group_constants"), on_host():
-        return _group_constants_host(cfg0, bank0, vdd_scale)
+        return _group_constants_host(cfg0, bank0, vdd_scale, t_ret)
 
 
-def _group_constants_host(cfg0: BankConfig, bank0, vdd_scale: float) -> dict:
+def _retention_row(cfg0: BankConfig, bank0, vdd_scale: float) -> tuple:
+    return ret_mod.integral_row(bank0.cell, cfg0.tech, wwlls=cfg0.wwlls,
+                                wwl_boost=cfg0.wwl_boost,
+                                vdd_scale=vdd_scale)
+
+
+def _retention(rows: Sequence[tuple]) -> List[float]:
+    """`retention.analyze(...).t_ret_s` of each row (`_retention_row`),
+    bit for bit, in one batched eager integral on the host CPU device;
+    rows pad to a power-of-two bucket of at least 8, so a cube's and a
+    campaign's lattices each reuse one set of eager-op shapes."""
+    lanes = pow2_bucket(len(rows), floor=8)
+    trace.count("dse_batch.retention_rows", len(rows))
+    trace.count("dse_batch.retention_lanes", lanes)
+    with trace.span("dse_batch.retention"), on_host():
+        t = ret_mod.t_ret_rows(pad_bucket(np.array(rows, np.float64), lanes))
+    return t[:len(rows)].tolist()
+
+
+def _group_constants_host(cfg0: BankConfig, bank0, vdd_scale: float,
+                          t_ret: Optional[float]) -> dict:
     tech = with_vdd_scale(cfg0.tech, vdd_scale)
     cell = bank0.cell
     if bank0.is_gc:
@@ -121,9 +145,8 @@ def _group_constants_host(cfg0: BankConfig, bank0, vdd_scale: float) -> dict:
             i_cell = cell.i_read(tech, v_sn, v_rbl_mid)
             off_sn = cell.v_sn_written(tech, 1 if cell.read_on_sn_low else 0)
             i_leak1 = cell.i_leak_rbl(tech, off_sn)
-        with trace.span("dse_batch.retention"):
-            t_ret = ret_mod.analyze(cell, tech, wwlls=cfg0.wwlls,
-                                    wwl_boost=cfg0.wwl_boost).t_ret_s
+        if t_ret is None:
+            t_ret, = _retention([_retention_row(cfg0, bank0, vdd_scale)])
         with trace.span("dse_batch.currents"):
             wf = cell.wf(tech)
             v_gate = tech.vdd + (cfg0.wwl_boost if cfg0.wwlls else 0.0)
@@ -154,14 +177,37 @@ def _group_constants_host(cfg0: BankConfig, bank0, vdd_scale: float) -> dict:
 _CONSTS_CACHE: Dict[tuple, tuple] = {}
 
 
-def _group_constants_cached(cfg0: BankConfig, bank0,
-                            vdd_scale: float) -> dict:
-    key = topology_key(cfg0) + (float(vdd_scale),)
+def _consts_key(cfg0: BankConfig, vdd_scale: float) -> tuple:
+    return topology_key(cfg0) + (float(vdd_scale),)
+
+
+def _group_constants_cached(cfg0: BankConfig, bank0, vdd_scale: float,
+                            t_ret: Optional[float] = None) -> dict:
+    key = _consts_key(cfg0, vdd_scale)
     hit = _CONSTS_CACHE.get(key)
     if hit is None:
         _CONSTS_CACHE[key] = hit = (
-            _group_constants(cfg0, bank0, vdd_scale), cfg0.tech)
+            _group_constants(cfg0, bank0, vdd_scale, t_ret=t_ret), cfg0.tech)
     return hit[0]
+
+
+def _fill_constants(heads, vdd_scales: Sequence[float]) -> None:
+    """Memoize the constants of every (topology group, rung) that
+    `_CONSTS_CACHE` lacks, one `_group_constants` call each, with the
+    retention of all the gain-cell ones from one `_retention` batch.
+    `heads` holds each group's first (config, bank). The batch has a row
+    per missing (group, rung) slot, repeated rungs included, so its
+    shape follows the lattice's shape alone: a lattice at repeated rungs
+    runs the shapes one at distinct rungs runs."""
+    slots = [(cfg0, bank0, v) for cfg0, bank0 in heads for v in vdd_scales
+             if _consts_key(cfg0, v) not in _CONSTS_CACHE]
+    gc = [s for s in slots if s[1].is_gc]
+    t_rets = dict(zip([_consts_key(c, v) for c, _, v in gc],
+                      _retention([_retention_row(*s) for s in gc]))) \
+        if gc else {}
+    for cfg0, bank0, v in slots:
+        _group_constants_cached(cfg0, bank0, v,
+                                t_rets.get(_consts_key(cfg0, v)))
 
 
 @lru_cache(maxsize=None)
@@ -367,9 +413,13 @@ def evaluate_vdd_lattice(cfgs: Sequence[BankConfig],
     area = np.zeros(P); bits = np.zeros(P); nw = np.zeros(P)
     is_gc = np.zeros(P, bool)
     with trace.span("dse_batch.lattice"):
+        groups = []
         for idx in group_by_topology(cfgs).values():
             sub = [cfgs[i] for i in idx]
-            banks = [build_bank(c) for c in sub]
+            groups.append((idx, sub, [build_bank(c) for c in sub]))
+        _fill_constants([(sub[0], banks[0]) for _, sub, banks in groups],
+                        vdd_scales)
+        for idx, sub, banks in groups:
             a = _eval_group_arrays(sub, banks, vdd_scales)
             cols = np.asarray(idx)
             for dst, src in (("f_max_hz", "f"), ("t_read_s", "t_read"),

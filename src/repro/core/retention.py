@@ -57,16 +57,27 @@ def leak_fn(cell: Bitcell, tech: TechFile):
     wf, rf = cell.wf(tech), cell.rf(tech)
 
     def fn(v_sn, vt0=wf.vt0, w=cell.w_write):
-        # write device off: gate at 0 (NMOS) with WBL at 0 -> discharges SN
-        i_w = channel_current_raw(
-            jnp.float32(wf.polarity), vt0, wf.n_slope, wf.k_prime,
-            wf.lambda_, w, cell.l_write,
-            jnp.float32(0.0 if wf.polarity > 0 else tech.vdd),
-            v_sn, jnp.float32(0.0))
-        i_g = rf.i_gate_a_per_um * cell.w_read * v_sn / 1.1
-        return jnp.abs(i_w) + i_g
+        return _sn_leak(jnp.float32(wf.polarity), vt0, wf.n_slope,
+                        wf.k_prime, wf.lambda_, w, cell.l_write,
+                        jnp.float32(_v_off(wf, tech)),
+                        rf.i_gate_a_per_um * cell.w_read, v_sn)
 
     return fn
+
+
+def _v_off(wf, tech: TechFile) -> float:
+    """Gate voltage that holds the write device off: 0 for n-type, vdd
+    for p-type."""
+    return 0.0 if wf.polarity > 0 else tech.vdd
+
+
+def _sn_leak(pol, vt0, n, kp, lam, w, l, v_off, g_read, v_sn):
+    """SN leak (A, discharging positive): the off write device (gate at
+    `v_off`, WBL at 0) plus the read gate, `g_read` amperes per volt at
+    1.1 V. Elementwise over broadcastable operands."""
+    i_w = channel_current_raw(pol, vt0, n, kp, lam, w, l, v_off, v_sn,
+                              jnp.float32(0.0))
+    return jnp.abs(i_w) + g_read * v_sn / 1.1
 
 
 def analyze(cell: Bitcell, tech: TechFile, *, wwlls=False, wwl_boost=0.55,
@@ -92,6 +103,41 @@ def _cross_time(i_of_v, c_sn, v0, v_margin, n_steps):
     vs = jnp.linspace(v_margin, v0, n_steps)
     inv_i = 1.0 / jnp.maximum(jax.vmap(i_of_v)(vs), 1e-30)
     return float(c_sn * jnp.trapezoid(inv_i, vs))
+
+
+def integral_row(cell: Bitcell, tech: TechFile, *, wwlls=False,
+                 wwl_boost=0.55, vdd_scale: float = 1.0) -> tuple:
+    """One row of `t_ret_rows`: the operands `analyze` integrates for the
+    same arguments, as Python floats (write-device polarity, vt0, n_slope,
+    k_prime, lambda, width, length; its off-gate voltage; the read-gate
+    leak coefficient `i_gate_a_per_um * w_read`; c_sn, v0, v_margin)."""
+    tech = with_vdd_scale(tech, vdd_scale)
+    wf, rf = cell.wf(tech), cell.rf(tech)
+    return (float(wf.polarity), wf.vt0, wf.n_slope, wf.k_prime, wf.lambda_,
+            cell.w_write, cell.l_write, _v_off(wf, tech),
+            rf.i_gate_a_per_um * cell.w_read, cell.sn_cap(tech),
+            cell.v_sn_written(tech, 1, wwlls=wwlls, wwl_boost=wwl_boost),
+            _margin_voltage(cell, tech))
+
+
+def t_ret_rows(rows, n_steps=4000) -> np.ndarray:
+    """`analyze(...).t_ret_s` of every row of `rows` ((R, 12) float64,
+    rows of `integral_row`) in one pass of the same eager f32 ops over an
+    (R, n_steps) grid, bit for bit. Each row's grid is its own eager
+    `jnp.linspace`, as in `analyze` (a broadcast one rounds differently);
+    the float64 columns round to f32 once, as `analyze`'s Python floats
+    do. Do not jit this: XLA's fusion rounds the integrand differently.
+    Rows with v0 <= v_margin are computed like the others and read 0.0,
+    so the shape is R whatever the rows hold."""
+    rows = np.asarray(rows, np.float64)
+    (pol, vt0, n, kp, lam, w, l, v_off, g_read, c_sn, v0,
+     v_m) = (rows[:, k:k + 1] for k in range(12))
+    vs = jnp.stack([jnp.linspace(a, b, n_steps)
+                    for a, b in zip(v_m[:, 0].tolist(), v0[:, 0].tolist())])
+    inv_i = 1.0 / jnp.maximum(
+        _sn_leak(pol, vt0, n, kp, lam, w, l, v_off, g_read, vs), 1e-30)
+    t = np.asarray(c_sn[:, 0] * jnp.trapezoid(inv_i, vs), np.float64)
+    return np.where(v0[:, 0] > v_m[:, 0], t, 0.0)
 
 
 def retention_vs_vt(cell: Bitcell, tech: TechFile, vt_values, *,

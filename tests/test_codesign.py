@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from repro.api import CoDesignQuery, CoDesignReport, Session, SweepQuery
-from repro.core import dse
+from repro.core import dse, trace
 from repro.core.bank import BankConfig
 from repro.core.dse import Demand, lattice_configs
 from repro.core.dse_batch import (banks_needed_grid, evaluate_vdd_lattice,
@@ -60,6 +60,25 @@ def test_scalar_evaluate_vdd_scale_moves_retention_and_speed():
     # geometry is voltage-independent
     assert lo.area_um2 == nom.area_um2 == hi.area_um2
     assert "vdd_scale" in nom.as_dict()
+
+
+def test_lattice_batches_retention_once():
+    """One lattice call: one batched retention integral over every
+    (gain-cell group, rung), and one constants call per (group, rung)."""
+    cfgs = lattice_configs(cells=("gc2t_nn", "gc2t_np", "gc2t_osos"),
+                           word_sizes=(16,), num_words=(16,),
+                           wwlls=(False, True))
+    scales = tuple(np.linspace(0.7123, 1.2123, 8).tolist())
+    with trace.recording() as rec:
+        lat = evaluate_vdd_lattice(cfgs, scales)
+    assert len(rec.named("dse_batch.retention")) == 1
+    assert len(rec.named("dse_batch.group_constants")) == 6 * 8
+    assert (rec.counters["dse_batch.retention_rows"],
+            rec.counters["dse_batch.retention_lanes"]) == (48, 64)
+    for vi, v in enumerate(scales):
+        for pi, cfg in enumerate(cfgs):
+            assert lat.point(vi, pi).retention_s == \
+                dse.evaluate(cfg, vdd_scale=v).retention_s
 
 
 def test_vdd_lattice_matches_scalar_reference(lat, scalar_points):

@@ -151,11 +151,15 @@ def test_codesign_constants_hold_retention_and_currents():
     assert len(consts) >= 4                 # 2+ groups x 2 fresh rungs
     for g in consts:
         kids = [s.name for s in rec.spans if s.parent == g.id]
-        assert "dse_batch.retention" in kids and "dse_batch.currents" in kids
+        assert "dse_batch.currents" in kids
+        assert "dse_batch.retention" not in kids
         assert "dse_batch.lattice" in [a.name for a in _ancestors(rec, g)]
+    # the lattice's retention is one batched span beside its constants
+    (ret,) = rec.named("dse_batch.retention")
+    assert [a.name for a in _ancestors(rec, ret)][0] == "dse_batch.lattice"
+    assert ret.end <= min(g.start for g in consts)
     assert rec.self_time("dse_batch.group_constants",
-                         ["dse_batch.retention", "dse_batch.currents"]
-                         ) >= 0.0
+                         ["dse_batch.currents"]) >= 0.0
 
 
 def test_nested_and_threaded_spans_keep_their_parents():
