@@ -4,10 +4,13 @@ records each one's inputs, result, host-clock times and outcome.
 One closed-loop client; each request runs through `Session.run` on a
 fresh `Session` (on the request's scaled deck where it has one), so no
 result is memoized between requests while compiled programs are shared.
-A request sent before the deadline runs to completion; the window ends
-at the last completion. `on_done(now)` is called after every completion
-(the traced run stops its trace there). Work units per request
-(transient points, cube entries) are counted from the request itself.
+A `serve` request is a replay on the run's one served model
+(`bench.lib.serving.Server`, built at the first such request, which is
+warm-up). A request sent before the deadline runs to completion; the
+window ends at the last completion. `on_done(now)` is called after
+every completion (the traced run stops its trace there). Work units per
+request (transient points, cube entries, decode tokens) are counted
+from the request itself.
 """
 from __future__ import annotations
 
@@ -31,6 +34,8 @@ class Record:
 
 
 def units(req: dict, config: dict) -> dict:
+    if req["type"] == "serve":
+        return {"decode_tokens": sum(o for _, _, o in req["prompts"])}
     sw = req if req["type"] == "sweep" else req["sweep"]
     p = len(traffic_mod.lattice(config["space"], sw["cells"],
                                 sw["word_sizes"], sw["num_words"]))
@@ -78,8 +83,21 @@ def _deck(req: dict):
 class Driver:
     def __init__(self, mix: dict, config: dict, seed: int):
         self.mix, self.config, self.seed = mix, config, seed
+        self.server = None
+
+    def reseed(self, seed: int) -> None:
+        """Draw the next window's requests (and a served model's
+        weights) from `seed`, keeping every compiled program."""
+        self.seed = seed
+        if self.server is not None:
+            self.server.reseed(seed)
 
     def send(self, req: dict):
+        if req["type"] == "serve":
+            if self.server is None:
+                from bench.lib.serving import Server
+                self.server = Server(self.config, self.seed)
+            return self.server.replay(req)
         from repro.api import Session
         return Session(tech=_deck(req)).run(to_query(req, self.config))
 

@@ -18,8 +18,10 @@ reference check and its lower-precision control), `traffic/<mix>.json`
 `metrics/<metric>.py` (a reader: `read(run) -> float | None`, with the
 spans it needs in `SPANS`). With `--trace 0` the line carries the cell's
 end-to-end metrics, with `--trace 1` its per-layer metrics, read from a
-profiler trace of the window and from host spans around the program's
-entry points.
+profiler trace of the window, from host spans around the program's
+entry points, and from the program's own spans and counters
+(`repro.core.trace`, recorded while traced and handed to readers as
+`RunData.recording`).
 """
 from __future__ import annotations
 
@@ -74,6 +76,9 @@ class RunData:
     trace: Optional[object] = None
     peaks: Optional[dict] = None
     traced: list = field(default_factory=list)   # completed while traced
+    # the program's spans and counters inside the requests completed
+    # while traced (None: untraced, or a program without its own spans)
+    recording: Optional[object] = None
 
     @property
     def done(self):
@@ -81,10 +86,11 @@ class RunData:
 
 
 class Traced:
-    """The traced part of a window: the profiler and the host spans run
-    from the window's start until the first completion `seconds` later
-    (a mix's `trace_seconds`; a trace of the fused transient engine grows
-    by some 100 MB a second), wrapped in a `bench.window` annotation."""
+    """The traced part of a window: the profiler, the host spans and the
+    program's own recording run from the window's start until the first
+    completion `seconds` later (a mix's `trace_seconds`; a trace of the
+    fused transient engine grows by some 100 MB a second), wrapped in a
+    `bench.window` annotation."""
 
     def __init__(self, log_dir: str, spans: Spans, seconds: float,
                  on: bool):
@@ -93,8 +99,16 @@ class Traced:
         self.log_dir, self.spans, self.limit = log_dir, spans, seconds
         self.on, self.n_done, self.seconds = on, 0, 0.0
         self.t_end = -math.inf
+        self.recording, self._recording = None, None
         if not on:
             return
+        try:
+            from repro.core import trace as program_trace
+        except ImportError:                 # a program without its spans
+            program_trace = None
+        if program_trace is not None:
+            self._recording = program_trace.recording()
+            self.recording = self._recording.__enter__()
         opts = ProfileOptions()
         opts.python_tracer_level = 0
         opts.host_tracer_level = 2
@@ -122,6 +136,24 @@ class Traced:
         self.seconds = self.t_end - self.t0
         self.annotation.__exit__(None, None, None)
         jax.profiler.stop_trace()
+        if self._recording is not None:
+            self._recording.__exit__(None, None, None)
+
+    def inside(self, traced):
+        """The program's recording cut to the requests in `traced`, each
+        from its send to its completion, on the host clock the records
+        share."""
+        if self.recording is None or not traced:
+            return None
+        from repro.core.trace import Recording
+        spans = [(r.t_send, r.t_done) for r in traced]
+
+        def within(t0, t1):
+            return any(a <= t0 and t1 <= b for a, b in spans)
+        rec = self.recording
+        return Recording(
+            spans=[s for s in rec.spans if within(s.start, s.end)],
+            counts=[c for c in rec.counts if within(c[0], c[0])])
 
 
 def _finite(x: float) -> float:
@@ -171,8 +203,10 @@ def run_cell(bench: dict, cell: dict, *, seed: int, seconds: float,
         trace_dir = os.path.join(TRACE_DIR, f"{cell['name']}.{seed}")
         traced = Traced(trace_dir, spans,
                         float(mix.get("trace_seconds", seconds)), trace)
-        records = driver.run(seconds, on_done=traced.on_done)
-        traced.stop()
+        try:
+            records = driver.run(seconds, on_done=traced.on_done)
+        finally:
+            traced.stop()
         window_s = driver.t1 - driver.t0
         inwin = clock.since(setup)
         log(f"window_s={window_s} requests={len(records)} "
@@ -191,6 +225,7 @@ def run_cell(bench: dict, cell: dict, *, seed: int, seconds: float,
 
         checks = checker.check(records, config, seed)
     finally:
+        spans.uninstall()
         if ctl is not None:
             ctl.__exit__(None, None, None)
     failed = sum(not r.ok for r in records)
@@ -211,9 +246,9 @@ def run_cell(bench: dict, cell: dict, *, seed: int, seconds: float,
     if dev0.platform != "cpu":
         from bench.lib.peaks import peaks_for
         peaks = peaks_for(dev0.device_kind)
+    inside = [r for r in records if r.t_done <= traced.t_end]
     run = RunData(config, records, window_s, setup_s, spans, summary,
-                  peaks,
-                  [r for r in records if r.t_done <= traced.t_end])
+                  peaks, inside, traced.inside(inside))
     metrics = {}
     for m in specs:
         v = readers[m["name"]].read(run)

@@ -46,3 +46,21 @@ def lanes(run) -> int:
     (the stop-time operand is (B,))."""
     return sum(s.info["args"][3][0]
                for s in run.spans.named(RUN_LATTICE["span"]))
+# serving/engine: one drained replay (admissions, decode chunks, the
+# host's reconcile between them); api: the measured co-design after it
+SERVE_RUN = {"module": "repro.serving.engine", "attr": "ServeEngine.run",
+             "span": "engine.ServeEngine.run"}
+CODESIGN_MEASURED = {"module": "repro.api.session",
+                     "attr": "Session.codesign_measured",
+                     "span": "api.Session.codesign_measured"}
+# device programs of the serving engine, by the module names the trace
+# gives the engine's jitted admission and decode-chunk functions
+ADMIT_MODULE = "jit__admit_kernel"
+CHUNK_MODULE = "jit__chunk"
+
+
+def served(run):
+    """(replay record, prompt length, answer length) of every engine
+    request in the replays completed while traced."""
+    return [(r, p, o) for r in run.traced if r.ok
+            for _, p, o in r.request["prompts"]]

@@ -1,16 +1,13 @@
 """Host prep of the transient characterization per real point, from the
-program's own spans (`bench.lib.program`): the self time of
+program's own spans (`RunData.recording`): the self time of
 `char_batch.prep` (banks, netlists, analytic estimates, stimulus, G/C
 assembly, bucket padding; less any program span below it) over the
 counter `char_batch.points`. Host clock, traced run; None where the
 program records no spans."""
-from bench.lib import program
-
-program.record()
 
 
 def read(run):
-    rec = program.window(run)
+    rec = run.recording
     n = rec.counters["char_batch.points"] if rec is not None else 0
     if not n:
         return None

@@ -1,15 +1,12 @@
 """Host time of the retention integral per group-constant evaluation,
-from the program's own spans (`bench.lib.program`): the
+from the program's own spans (`RunData.recording`): the
 `dse_batch.retention` spans over the `dse_batch.group_constants` spans
 (one per topology group and vdd rung). Host clock, traced run; None
 where the program records no spans."""
-from bench.lib import program
-
-program.record()
 
 
 def read(run):
-    rec = program.window(run)
+    rec = run.recording
     groups = rec.named("dse_batch.group_constants") if rec is not None \
         else []
     if not groups:

@@ -1,0 +1,31 @@
+"""The decode-chunk program's share of its roofline: least time over its
+device time in the trace (module `jit__chunk`). Least time of a traced
+replay: the larger of its decode FLOPs over the bf16 peak and the bytes
+its decode steps must move over HBM bandwidth (`bench.lib.work`: every
+weight once a step, and each live request's resident cache rows once a
+step, not the whole window the program reads); summed over the
+replays."""
+from bench.lib import layers
+from bench.lib.work import DecoderWork
+
+SPANS = (layers.SERVE_RUN,)
+
+
+def read(run):
+    w = DecoderWork(run.config["model"])
+    if run.trace is None or run.peaks is None:
+        return None
+    t = run.trace.module_time(layers.CHUNK_MODULE)
+    least = 0.0
+    for r in run.traced:
+        steps = r.result["decode_steps"] if r.ok else 0
+        if not steps:
+            continue
+        po = [(p, o) for _, p, o in r.request["prompts"]]
+        batch = sum(o - 1 for _, o in po) / steps
+        flops = sum(w.decode_flops(p, o) for p, o in po)
+        moved = steps * w.decode_weight_bytes(batch) + sum(
+            w.decode_kv_bytes(p, o) for p, o in po)
+        least += max(flops / run.peaks["bf16_flops_per_s"],
+                     moved / run.peaks["hbm_bytes_per_s"])
+    return 100.0 * least / t if least > 0 and t > 0 else None

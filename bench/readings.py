@@ -5,7 +5,8 @@
         [--seconds 8] [--control]
 
 Warms the cell up once, then for each seed drives a short window at the
-cell's own load (the mix's requests, drawn from that seed) and runs the
+cell's own load (the mix's requests, and a served model's weights, drawn
+from that seed) and runs the
 configuration's check on what it produced, printing one JSON line per
 seed with every compared number. With `--control` the configuration's
 lower-precision control is switched on for all of it: its numbers are
@@ -37,9 +38,11 @@ def readings(cell: dict, seeds, seconds: float, control: bool):
         config = ctl.config
         ctl.__enter__()
     try:
-        drivers.Driver(mix, config, 0).warmup()
+        driver = drivers.Driver(mix, config, seeds[0])
+        driver.warmup()
         for seed in seeds:
-            records = drivers.Driver(mix, config, seed).run(seconds)
+            driver.reseed(seed)
+            records = driver.run(seconds)
             checks = checker.readings(records, config, seed)
             checks["requests_failed"] = {
                 "value": sum(not r.ok for r in records), "limit": 0}

@@ -1,14 +1,15 @@
-"""The readers of the program's own spans and counters: traced runs of
-both cells on CPU at a tiny size read a number for each, a run that
-raises leaves no recording in the next run's way, and on a program
-without `repro.core.trace` every one of them reads None."""
+"""The readers of the program's own spans and counters, which the
+harness records while traced and hands them as `RunData.recording`:
+traced runs of both cells on CPU at a tiny size read a number for each,
+a run that raises leaves no recording open, and on a program without
+`repro.core.trace` every one of them reads None."""
 import json
 import os
 import sys
 
 import pytest
 
-from bench.lib import harness, program, traffic
+from bench.lib import harness, traffic
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -51,34 +52,25 @@ def test_traced_run_reads_each_program_metric(bench, name):
         assert m["pad_share.transient"]["value"] == pytest.approx(25.0)
         assert m["char_prep_host_ms_per_point.transient"]["value"] <= \
             m["char_prep_ms_per_point.transient"]["value"]
-    assert program._open is None            # closed once read
+    from repro.core import trace
+    assert trace._ACTIVE is None            # closed with the trace
 
 
-def test_failed_run_leaves_no_recording_in_the_way(bench, monkeypatch):
+def test_failed_run_leaves_no_recording_open(bench, monkeypatch):
     from repro.core import trace
 
-    def fail(records, config, seed):
-        raise RuntimeError("the check failed")
+    def fail(*args, **kwargs):
+        raise RuntimeError("the window failed")
 
-    def load(kind, name):                   # the check raises after the
-        mod = load_module(kind, name)       # window, before any read
-        if kind == "configs":
-            mod.check = fail
-        return mod
-    load_module = harness.load_module
-    with monkeypatch.context() as m:
-        m.setattr(harness, "load_module", load)
-        with pytest.raises(RuntimeError, match="the check failed"):
+    with monkeypatch.context() as m:        # raises inside the window
+        m.setattr(harness.drivers_mod.Driver, "run", fail)
+        with pytest.raises(RuntimeError, match="the window failed"):
             traced_run(bench, "codesign.paper")
-    stale = program._last
-    assert trace._ACTIVE is stale and stale.spans   # still open
+    assert trace._ACTIVE is None                   # closed on the way out
     out = traced_run(bench, "codesign.paper")      # opens its own
-    assert program._last is not stale and trace._ACTIVE is None
+    assert trace._ACTIVE is None
     assert out["metrics"]["consts_retention_ms_per_group.codesign"][
         "value"] > 0
-    program.record()                                # a run that fails
-    program.close()                                 # closed at exit
-    assert trace._ACTIVE is None
 
 
 def test_readers_read_none_without_program_spans(bench, monkeypatch):
@@ -87,7 +79,8 @@ def test_readers_read_none_without_program_spans(bench, monkeypatch):
     monkeypatch.setitem(sys.modules, "repro.core.trace", None)
     readers = [harness.load_module("metrics", k)
                for ks in NEW.values() for k in ks]
-    assert program._open is None and program._last is None
+    traced = harness.Traced("unused", None, 1.0, on=False)
+    assert traced.recording is None and traced.inside([object()]) is None
     run = harness.RunData(config={}, records=[], window_s=1.0, setup_s=1.0,
-                          spans=None, traced=[object()])
+                          spans=None, traced=[object()], recording=None)
     assert [r.read(run) for r in readers] == [None] * len(readers)

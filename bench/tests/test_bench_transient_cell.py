@@ -31,10 +31,10 @@ def cell():
     return bench, c, cfg
 
 
-def run(cell, **kw):
+def run(cell, seconds=1.0, **kw):
     bench, c, cfg = cell
-    return harness.run_cell(bench, c, seed=SEED, seconds=1.0, trace=False,
-                            t_start=0.0, config=cfg, **kw)
+    return harness.run_cell(bench, c, seed=SEED, seconds=seconds,
+                            trace=False, t_start=0.0, config=cfg, **kw)
 
 
 def test_sound_run_is_correct(cell):
@@ -84,7 +84,9 @@ def test_fault_half_batch_left_out(cell, monkeypatch):
         return {k: thin(v) if np.ndim(v) else v for k, v in out.items()}
 
     monkeypatch.setattr(Transient, "run_lattice", half)
-    assert not run(cell)["correct"]
+    # only some campaigns show the fault (two alone read correct), so the
+    # window is long enough to hold several on a loaded host too
+    assert not run(cell, seconds=4.0)["correct"]
 
 
 def test_fault_answer_altered(cell, monkeypatch):
