@@ -11,6 +11,7 @@ from repro.configs.llama3_2_1b import CONFIG as LLAMA3_2_1B
 from repro.configs.arctic_480b import CONFIG as ARCTIC_480B
 from repro.configs.mixtral_8x7b import CONFIG as MIXTRAL_8X7B
 from repro.configs.internvl2_1b import CONFIG as INTERNVL2_1B
+from repro.configs.deepseek_v2_lite import CONFIG as DEEPSEEK_V2_LITE
 
 REGISTRY = {
     c.name: c
@@ -25,6 +26,7 @@ REGISTRY = {
         ARCTIC_480B,
         MIXTRAL_8X7B,
         INTERNVL2_1B,
+        DEEPSEEK_V2_LITE,
     ]
 }
 

@@ -51,7 +51,22 @@ class ModelConfig:
     n_experts: int = 0
     top_k: int = 0
     moe_dense_ff: int = 0            # arctic: parallel dense residual FFN
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # training only: inference is dropless
+    moe_d_ff: int = 0                # routed expert width (0 -> d_ff)
+    n_shared_experts: int = 0        # experts every token passes through
+    first_k_dense: int = 0           # leading dense layers before the MoE
+    norm_topk: bool = True           # renormalize the top-k gates
+    experts_held: int = 0            # routed experts held here, from
+                                     # expert 0 (0 -> all n_experts)
+
+    # --- latent attention (MLA, deepseek-v2) ---
+    attn_kind: str = "gqa"           # gqa | mla
+    kv_lora_rank: int = 0            # latent c_kv width
+    qk_nope_dim: int = 0             # per-head query/key part without rope
+    qk_rope_dim: int = 0             # per-head rope part (one shared key)
+    v_head_dim: int = 0
+    rope_scaling: tuple = ()         # YaRN: (key, value) pairs of HF's
+                                     # rope_scaling (a dict is accepted)
 
     # --- SSM / hybrid (zamba2) ---
     ssm_state: int = 0               # Mamba2 d_state
@@ -86,8 +101,33 @@ class ModelConfig:
     optimizer: str = "adamw"         # adamw | adafactor
     schedule: str = "cosine"         # cosine | wsd
 
+    def __post_init__(self):
+        if self.first_k_dense and self.attn_kind != "mla":
+            raise ValueError("leading dense layers decode through a latent "
+                             "cache only (attn_kind='mla')")
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
+        else:
+            object.__setattr__(self, "rope_scaling",
+                               tuple(tuple(kv) for kv in self.rope_scaling))
+
     def hd(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    def expert_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    def n_held(self) -> int:
+        """Routed experts whose weights this device holds."""
+        return self.experts_held or self.n_experts
+
+    def cache_row_elems(self) -> int:
+        """Elements of one cache row (one position, one layer): the
+        latent c_kv and the shared rope key under MLA, else K and V."""
+        if self.attn_kind == "mla":
+            return self.kv_lora_rank + self.qk_rope_dim
+        return 2 * self.n_kv_heads * self.hd()
 
     @property
     def is_encdec(self) -> bool:
@@ -106,7 +146,10 @@ class ModelConfig:
         return out
 
     def reduced(self) -> "ModelConfig":
-        """Tiny same-family config for CPU smoke tests."""
+        """Tiny same-family config for CPU smoke tests. Latent attention,
+        shared experts, the held share (an eighth of 16 experts) and the
+        leading dense layers are kept at tiny widths."""
+        mla = self.attn_kind == "mla"
         return dataclasses.replace(
             self,
             name=self.name + "-reduced",
@@ -117,7 +160,15 @@ class ModelConfig:
             head_dim=16,
             d_ff=128 if self.d_ff else 0,
             vocab_size=256,
-            n_experts=min(self.n_experts, 4),
+            n_experts=min(self.n_experts,
+                          16 if self.experts_held or self.top_k > 4 else 4),
+            experts_held=2 if self.experts_held else 0,
+            moe_d_ff=32 if self.moe_d_ff else 0,
+            first_k_dense=min(self.first_k_dense, 1),
+            kv_lora_rank=32 if mla else 0,
+            qk_nope_dim=16 if mla else 0,
+            qk_rope_dim=8 if mla else 0,
+            v_head_dim=16 if mla else 0,
             moe_dense_ff=64 if self.moe_dense_ff else 0,
             ssm_state=16 if self.ssm_state else 0,
             ssm_headdim=16,

@@ -9,16 +9,23 @@ costing).
 `block_skip=True` enables causal block skipping (lax.cond around fully
 masked KV blocks) — a §Perf hillclimb knob; baseline computes all blocks
 with masking.
+
+Multi-head latent attention (MLA, DeepSeek-V2, arXiv:2405.04434) is the
+second kind (`cfg.attn_kind == "mla"`, `mla_*` below): prefill expands
+the latent `c_kv` to per-head keys and values and runs the same flash
+path; decode is absorbed and reads and writes latent cache rows only.
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.common import dense_init, dtype_of, rope, shard_act
+from repro.models.common import (dense_init, dtype_of, rms_norm, rope,
+                                 shard_act)
 
 NEG_INF = -1e30
 
@@ -73,12 +80,15 @@ def _qkv(p, x, cfg, positions=None, use_rope=True):
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
-                    kv_len=None, chunk_q=512, chunk_kv=1024, block_skip=False):
-    """q: (B, Sq, H, hd); k, v: (B, Skv, K, hd); H = K * G. Returns (B, Sq, H, hd).
+                    kv_len=None, chunk_q=512, chunk_kv=1024, block_skip=False,
+                    scale=None):
+    """q, k: (B, S, H or K, hd); v: (B, Skv, K, hv); H = K * G. Returns
+    (B, Sq, H, hv). `scale` defaults to hd ** -0.5.
 
     Online-softmax over KV chunks; fp32 accumulation; GQA via head groups.
     """
     B, Sq, H, hd = q.shape
+    hv = v.shape[-1]
     Skv, K = k.shape[1], k.shape[2]
     G = H // K
     cq = min(chunk_q, Sq)
@@ -95,11 +105,12 @@ def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
         v = jnp.pad(v, ((0, 0), (0, Skvp - Skv), (0, 0), (0, 0)))
         Sq, Skv = Sqp, Skvp
     nq, nkv = Sq // cq, Skv // ckv
-    scale = 1.0 / np.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / np.sqrt(hd)
 
     qg = q.reshape(B, nq, cq, K, G, hd)
     kc = k.reshape(B, nkv, ckv, K, hd)
-    vc = v.reshape(B, nkv, ckv, K, hd)
+    vc = v.reshape(B, nkv, ckv, K, hv)
 
     def q_chunk_body(qi):
         qq = qg[:, qi]  # (B, cq, K, G, hd)
@@ -152,17 +163,17 @@ def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
 
         m0 = jnp.full((B, K, G, cq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, K, G, cq), jnp.float32)
-        a0 = jnp.zeros((B, K, G, cq, hd), jnp.float32)
+        a0 = jnp.zeros((B, K, G, cq, hv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(kv_body, (m0, l0, a0), jnp.arange(nkv))
         out = acc / jnp.maximum(l[..., None], 1e-30)
-        return out.transpose(0, 3, 1, 2, 4).reshape(B, cq, H, hd)  # (B,cq,H,hd)
+        return out.transpose(0, 3, 1, 2, 4).reshape(B, cq, H, hv)  # (B,cq,H,hv)
 
     if nq == 1:
         out = q_chunk_body(jnp.int32(0))[:, None]
     else:
-        out = jax.lax.map(q_chunk_body, jnp.arange(nq))  # (nq, B, cq, H, hd)
+        out = jax.lax.map(q_chunk_body, jnp.arange(nq))  # (nq, B, cq, H, hv)
         out = out.transpose(1, 0, 2, 3, 4)
-    return out.reshape(B, Sq, H, hd)[:, :Sq0].astype(q.dtype)
+    return out.reshape(B, Sq, H, hv)[:, :Sq0].astype(q.dtype)
 
 
 def _seqpar_flash(q, k, v, mesh, *, causal, window, block_skip):
@@ -342,3 +353,159 @@ def seed_ring_cache(k, v, window):
     ck = jnp.zeros((B, W, K, hd), k.dtype).at[:, idx].set(k[:, S - W:])
     cv = jnp.zeros((B, W, K, hd), v.dtype).at[:, idx].set(v[:, S - W:])
     return ck, cv
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (MLA). Per token: q = x Wq gives each head a
+# no-rope part (qk_nope_dim) and a rope part (qk_rope_dim); x Wkv_a gives
+# the latent c_kv (kv_lora_rank), RMS-normed, and ONE rope key k_pe shared
+# by every head. Per-head keys are [c_kv Wk_b, k_pe] and values c_kv Wv_b
+# (HF's kv_b_proj, split into its key and value halves). Rope pairs are
+# interleaved as in the HF code: pair i is (x[2i], x[2i+1]), and the
+# rotated vector comes out de-interleaved ([rotated evens, rotated odds]);
+# q and k take the same layout, so their product is the paper's.
+# ---------------------------------------------------------------------------
+
+def yarn(cfg):
+    """(inverse frequencies of the rope pairs (f32, qk_rope_dim/2),
+    cos/sin scale, softmax scale) under the config's YaRN scaling
+    (`rope_scaling`; plain rope and qk_head_dim ** -0.5 without it)."""
+    dim, base = cfg.qk_rope_dim, cfg.rope_theta
+    inv = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    rs = dict(cfg.rope_scaling)
+    if not rs:
+        return inv, 1.0, scale
+    f = float(rs["factor"])
+    orig = float(rs["original_max_position_embeddings"])
+
+    def corr(rot):
+        return dim * math.log(orig / (rot * 2 * math.pi)) / (
+            2 * math.log(base))
+
+    def mscale(m):
+        return 0.1 * m * math.log(f) + 1.0 if f > 1 else 1.0
+    lo = max(math.floor(corr(rs["beta_fast"])), 0)
+    hi = min(math.ceil(corr(rs["beta_slow"])), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - lo)
+                   / (hi - lo if hi > lo else 0.001), 0, 1)
+    keep = (1.0 - ramp).astype(np.float32)   # 1: extrapolate, 0: interpolate
+    inv = (inv / np.float32(f)) * (1 - keep) + inv * keep
+    return (inv.astype(np.float32),
+            mscale(rs["mscale"]) / mscale(rs["mscale_all_dim"]),
+            scale * mscale(rs["mscale_all_dim"]) ** 2)
+
+
+def rope_pairs(x, positions, inv_freq, mscale=1.0):
+    """x: (..., S, H, dr), positions broadcastable to (..., S); pairs
+    (x[2i], x[2i+1]) rotate at inv_freq[i], out de-interleaved."""
+    ang = positions[..., None].astype(jnp.float32) * jnp.asarray(inv_freq)
+    cos = (jnp.cos(ang) * mscale)[..., None, :]
+    sin = (jnp.sin(ang) * mscale)[..., None, :]
+    xe = x[..., 0::2].astype(jnp.float32)
+    xo = x[..., 1::2].astype(jnp.float32)
+    return jnp.concatenate([xe * cos - xo * sin, xo * cos + xe * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def mla_init(key, cfg):
+    d, H, R = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    dt = dtype_of(cfg)
+    ks = jax.random.split(key, 5)
+    return {
+        "wq": dense_init(ks[0], (d, H, dn + dr), dt),
+        "wkv_a": dense_init(ks[1], (d, R + dr), dt),
+        "kv_norm": jnp.ones((R,), dt),
+        "wk_b": dense_init(ks[2], (R, H, dn), dt),
+        "wv_b": dense_init(ks[3], (R, H, dv), dt),
+        "wo": dense_init(ks[4], (H, dv, d), dt, scale=1.0 / np.sqrt(H * dv)),
+    }
+
+
+def mla_specs(cfg):
+    return {
+        "wq": ("embed", "heads", "head_dim"),
+        "wkv_a": ("embed", "kv_lora"),
+        "kv_norm": ("kv_lora",),
+        "wk_b": ("kv_lora", "heads", "head_dim"),
+        "wv_b": ("kv_lora", "heads", "head_dim"),
+        "wo": ("heads", "head_dim", "embed"),
+    }
+
+
+def _mla_project(p, x, positions, cfg):
+    """x: (B, S, d) -> q_nope (B,S,H,dn), q_pe (B,S,H,dr), c_kv (B,S,R),
+    k_pe (B,S,dr), softmax scale; rope applied."""
+    R, dn = cfg.kv_lora_rank, cfg.qk_nope_dim
+    inv, msc, scale = yarn(cfg)
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+    kv = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"])
+    c = rms_norm(kv[..., :R], p["kv_norm"], cfg.norm_eps)
+    k_pe = rope_pairs(kv[..., None, R:], positions, inv, msc)[..., 0, :]
+    q_pe = rope_pairs(q[..., dn:], positions, inv, msc)
+    return q[..., :dn], q_pe, c, k_pe, scale
+
+
+def mla_attend_train(p, x, positions, cfg, *, block_skip=False):
+    """Full training/prefill MLA, unabsorbed: c_kv expanded to per-head
+    keys and values. Returns (out (B,S,d), (c_kv, k_pe)), the latter
+    the layer's cache rows."""
+    q_nope, q_pe, c, k_pe, scale = _mla_project(p, x, positions, cfg)
+    B, S, H, _ = q_nope.shape
+    k_nope = jnp.einsum("bsr,rhk->bshk", c, p["wk_b"])
+    v = jnp.einsum("bsr,rhk->bshk", c, p["wv_b"])
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_pe[:, :, None], (B, S, H,
+                                                    k_pe.shape[-1]))], -1)
+    o = flash_attention(q, k, v, causal=True, block_skip=block_skip,
+                        scale=scale)
+    o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+    return shard_act(o, "batch", "seq", "embed"), (c, k_pe)
+
+
+def mla_decode(p, x, cache, layer, pos, cfg):
+    """Absorbed decode of one token against the whole latent cache.
+
+    x: (B, 1, d); cache: {"ckv": (L, B, W, R), "kpe": (L, B, W, dr)} and,
+    for an int8 cache, per-row scales "ckv_sc", "kpe_sc" (L, B, W);
+    layer: this layer's index into it; pos: (B,) position. Writes the
+    position's row into the layer's rows in place, then attends over
+    them: Wk_b is folded into the query (q_lat = Wk_b q_nope, scores
+    q_lat . c_kv + q_pe . k_pe) and Wv_b applied after the weighted sum
+    of latent rows. No per-head key or value is formed. Returns
+    (out (B, 1, d), cache)."""
+    q_nope, q_pe, c, k_pe, scale = _mla_project(p, x, pos[:, None], cfg)
+    B, W = x.shape[0], cache["ckv"].shape[2]
+    slot = jnp.minimum(pos, W - 1)
+    bidx = jnp.arange(B)
+    int8 = "ckv_sc" in cache
+    new = dict(cache)
+    for name, row in (("ckv", c[:, 0]), ("kpe", k_pe[:, 0])):
+        if int8:
+            row, sc = quantize_kv(row)
+            new[name + "_sc"] = cache[name + "_sc"].at[layer, bidx,
+                                                       slot].set(sc)
+        new[name] = cache[name].at[layer, bidx, slot].set(
+            row.astype(cache[name].dtype))
+    rows = {k: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+            for k, a in new.items()}
+    dt = q_nope.dtype
+    q_lat = jnp.einsum("bhk,rhk->bhr", q_nope[:, 0], p["wk_b"])
+    s_c = jnp.einsum("bhr,bwr->bhw", q_lat, rows["ckv"].astype(dt),
+                     preferred_element_type=jnp.float32)
+    s_r = jnp.einsum("bhk,bwk->bhw", q_pe[:, 0], rows["kpe"].astype(dt),
+                     preferred_element_type=jnp.float32)
+    if int8:
+        s_c = s_c * rows["ckv_sc"].astype(jnp.float32)[:, None]
+        s_r = s_r * rows["kpe_sc"].astype(jnp.float32)[:, None]
+    s = (s_c + s_r) * scale
+    valid = jnp.arange(W)[None] <= slot[:, None]
+    s = jnp.where(valid[:, None], s, NEG_INF)
+    w = jax.nn.softmax(s, axis=-1)
+    if int8:
+        w = w * rows["ckv_sc"].astype(jnp.float32)[:, None]
+    o_lat = jnp.einsum("bhw,bwr->bhr", w.astype(dt), rows["ckv"].astype(dt))
+    o = jnp.einsum("bhr,rhk->bhk", o_lat, p["wv_b"])
+    return jnp.einsum("bhk,hkd->bd", o, p["wo"])[:, None], new

@@ -2,7 +2,10 @@
 
 Families:
   dense / vlm   - stacked dense decoder blocks (vlm prepends patch embeds)
-  moe           - stacked MoE decoder blocks (arctic adds dense residual)
+  moe           - stacked MoE decoder blocks (arctic adds dense residual;
+                  deepseek-v2: shared experts, `first_k_dense` leading
+                  dense blocks before the stack, latent attention with a
+                  latent cache (c_kv, k_pe) updated in place in decode)
   hybrid        - zamba2: 54 Mamba2 layers + ONE shared attn+MLP block
                   applied after every `attn_every` Mamba layers
   ssm           - xlstm: groups of (slstm_every-1) mLSTM + 1 sLSTM
@@ -15,8 +18,9 @@ API:
   init(key)                                -> params
   param_specs()                            -> logical-axis tree
   loss(params, batch)                      -> (scalar, metrics)
-  prefill(params, batch)                   -> (logits_last, cache, pos)
+  prefill(params, batch, W[, into])        -> (logits_last, cache, pos)
   decode_step(params, cache, token, pos)   -> (logits, cache)
+                                              (+ held (B,), routed=True)
   decode_loop(params, cache, token, pos, emitted, max_new, done, eos,
               sample_fn, keys, n_tokens=K) -> fused K-token decode scan
   init_cache(B, W)                         -> zeroed cache tree
@@ -82,7 +86,12 @@ class Model:
         if fam in ("dense", "vlm"):
             p["blocks"] = _stack_init(tfm.dense_block_init, k_blocks, cfg.n_layers, cfg)
         elif fam == "moe":
-            p["blocks"] = _stack_init(tfm.moe_block_init, k_blocks, cfg.n_layers, cfg)
+            k0 = cfg.first_k_dense
+            if k0:
+                p["dense_blocks"] = _stack_init(tfm.dense_block_init,
+                                                k_extra, k0, cfg)
+            p["blocks"] = _stack_init(tfm.moe_block_init, k_blocks,
+                                      cfg.n_layers - k0, cfg)
         elif fam == "hybrid":
             p["mamba"] = _stack_init(ssm.init, k_blocks, cfg.n_layers, cfg)
             p["shared_attn"] = tfm.dense_block_init(k_extra, cfg)
@@ -108,6 +117,8 @@ class Model:
         if fam in ("dense", "vlm"):
             p["blocks"] = _add_layer_axis(tfm.dense_block_specs(cfg))
         elif fam == "moe":
+            if cfg.first_k_dense:
+                p["dense_blocks"] = _add_layer_axis(tfm.dense_block_specs(cfg))
             p["blocks"] = _add_layer_axis(tfm.moe_block_specs(cfg))
         elif fam == "hybrid":
             p["mamba"] = _add_layer_axis(ssm.specs(cfg))
@@ -151,6 +162,14 @@ class Model:
             h, _ = jax.lax.scan(_remat(body, cfg.remat), h, p["blocks"])
 
         elif fam == "moe":
+            if "dense_blocks" in p:
+                def dbody(x, bp):
+                    return tfm.dense_block_apply(
+                        bp, x, positions, cfg,
+                        block_skip=self.block_skip), None
+                h, _ = jax.lax.scan(_remat(dbody, cfg.remat), h,
+                                    p["dense_blocks"])
+
             def body(carry, bp):
                 x, a = carry
                 x, al = tfm.moe_block_apply(bp, x, positions, cfg, mesh=mesh,
@@ -259,6 +278,16 @@ class Model:
         dt = dtype_of(cfg)
         K, hd, L = cfg.n_kv_heads, cfg.hd(), cfg.n_layers
         fam = cfg.family
+        if fam in ("dense", "vlm", "moe") and cfg.attn_kind == "mla":
+            # a latent cache is kept in kv_dtype (int8 with per-row scales)
+            rows = (L, B, W)
+            cdt = jnp.dtype(cfg.kv_dtype)
+            cache = {"ckv": jnp.zeros(rows + (cfg.kv_lora_rank,), cdt),
+                     "kpe": jnp.zeros(rows + (cfg.qk_rope_dim,), cdt)}
+            if self._int8_kv():
+                cache.update(ckv_sc=jnp.zeros(rows, jnp.bfloat16),
+                             kpe_sc=jnp.zeros(rows, jnp.bfloat16))
+            return cache
         if fam in ("dense", "vlm", "moe"):
             W = self.kv_window(W)
             if self._int8_kv():
@@ -312,6 +341,12 @@ class Model:
         fam = self.cfg.family
         kv = ("layers", "batch", "kv_seq", "kv_heads", "head_dim")
         sc = ("layers", "batch", "kv_seq", "kv_heads")
+        if fam in ("dense", "vlm", "moe") and self.cfg.attn_kind == "mla":
+            lat = {"ckv": ("layers", "batch", "kv_seq", "kv_lora"),
+                   "kpe": ("layers", "batch", "kv_seq", None)}
+            if self._int8_kv():
+                lat.update(ckv_sc=sc[:3], kpe_sc=sc[:3])
+            return lat
         if fam in ("dense", "vlm", "moe"):
             if self._int8_kv():
                 return {"k": kv, "v": kv, "ksc": sc, "vsc": sc}
@@ -335,9 +370,12 @@ class Model:
 
     # ------------------------------------------------------------------
     # prefill: full forward that also builds the cache; returns logits of
-    # the last position. W (cache window) == padded cache length.
+    # the last position. W (cache window) == padded cache length. With
+    # `into=(cache, slots)` (a latent cache) each layer writes its rows
+    # straight into those slots of the serving engine's cache instead, in
+    # place, and that cache is returned.
     # ------------------------------------------------------------------
-    def prefill(self, p, batch, W=None):
+    def prefill(self, p, batch, W=None, into=None):
         cfg, mesh = self.cfg, self.mesh
         fam = cfg.family
         tokens = batch["tokens"]
@@ -354,7 +392,13 @@ class Model:
             pad[2] = (0, W_eff - Sk)
             return jnp.pad(k, pad)
 
-        if fam in ("dense", "vlm", "moe"):
+        if fam in ("dense", "vlm", "moe") and cfg.attn_kind == "mla":
+            h = self._embed(p, tokens)
+            S = h.shape[1]
+            positions = jnp.broadcast_to(jnp.arange(S), (B, S))
+            h, cache = self._prefill_latent(p, h, positions, W or S, into)
+
+        elif fam in ("dense", "vlm", "moe"):
             if fam == "vlm":
                 patches = batch["patches"].astype(dtype_of(cfg))
                 h = jnp.concatenate([patches, self._embed(p, tokens)], axis=1)
@@ -471,38 +515,47 @@ class Model:
     # ------------------------------------------------------------------
     # decode: one token against the cache
     # ------------------------------------------------------------------
-    def decode_step(self, p, cache, token, pos):
-        """token: (B, 1) int32; pos: (B,) int32. Returns (logits, cache)."""
+    def decode_step(self, p, cache, token, pos, routed=False):
+        """token: (B, 1) int32; pos: (B,) int32. Returns (logits, cache);
+        with `routed` (MoE) also each slot's assignments to the experts
+        held here, summed over layers (B,) int32."""
         cfg, mesh = self.cfg, self.mesh
         fam = cfg.family
         x = self._embed(p, token)
         ring = cfg.sliding_window > 0
+        held = None
 
-        if fam in ("dense", "vlm", "moe"):
+        if fam in ("dense", "vlm", "moe") and cfg.attn_kind == "mla":
+            x, cache, held = self._decode_latent(p, cache, x, pos)
+
+        elif fam in ("dense", "vlm", "moe"):
             int8 = self._int8_kv()
             dec = tfm.moe_block_decode if fam == "moe" else \
                 tfm.dense_block_decode
             kw = {"mesh": mesh} if fam == "moe" else {}
 
+            # a MoE block's last output is its held assignments (B,),
+            # stacked over layers as the scan's last output
             if int8:
                 def body(x, xs):
                     bp, ck, cv, ksc, vsc = xs
-                    y, ck, cv, (ksc, vsc) = dec(bp, x, ck, cv, pos, cfg,
-                                                ring=ring,
-                                                scales=(ksc, vsc), **kw)
-                    return y, (ck, cv, ksc, vsc)
-                x, (ks, vs, kss, vss) = jax.lax.scan(
+                    out = dec(bp, x, ck, cv, pos, cfg, ring=ring,
+                              scales=(ksc, vsc), **kw)
+                    return out[0], out[1:3] + tuple(out[3]) + out[4:]
+                x, ys = jax.lax.scan(
                     body, x, (p["blocks"], cache["k"], cache["v"],
                               cache["ksc"], cache["vsc"]))
-                cache = {"k": ks, "v": vs, "ksc": kss, "vsc": vss}
+                cache = dict(zip(("k", "v", "ksc", "vsc"), ys[:4]))
             else:
                 def body(x, xs):
                     bp, ck, cv = xs
-                    y, ck, cv = dec(bp, x, ck, cv, pos, cfg, ring=ring, **kw)
-                    return y, (ck, cv)
-                x, (ks, vs) = jax.lax.scan(
+                    out = dec(bp, x, ck, cv, pos, cfg, ring=ring, **kw)
+                    return out[0], out[1:]
+                x, ys = jax.lax.scan(
                     body, x, (p["blocks"], cache["k"], cache["v"]))
-                cache = {"k": ks, "v": vs}
+                cache = {"k": ys[0], "v": ys[1]}
+            if fam == "moe":
+                held = jnp.sum(ys[-1], axis=0)
 
         elif fam == "audio":
             x = x + sinusoid_at(pos, cfg.d_model).astype(x.dtype)
@@ -571,7 +624,80 @@ class Model:
 
         h = norm(x, p["final_norm"], cfg)
         logits = self._logits_last(p, h[:, -1])
+        if routed:
+            return logits, cache, held
         return logits, cache
+
+    def _latent_rows(self, ckv, kpe):
+        """Latent cache entries of rows (..., R) and (..., dr), in the
+        cache's type (int8 with per-row scales)."""
+        out = {}
+        for name, rows in (("ckv", ckv), ("kpe", kpe)):
+            if self._int8_kv():
+                out[name], out[name + "_sc"] = attention.quantize_kv(rows)
+            else:
+                out[name] = rows.astype(self.cfg.kv_dtype)
+        return out
+
+    def _prefill_latent(self, p, h, positions, W, into=None):
+        """Prefill's layers (the leading dense ones, then the stack), each
+        writing its latent rows of the batch straight into the cache, the
+        cache riding in the scan's carry so that no stack of every layer's
+        rows is formed. `into=(cache, slots)`: the serving engine's cache
+        and the batch's slots in it ((B,), out-of-range entries dropped),
+        whose rows past the prompt keep what they held (decode writes each
+        before it is attended); else a new cache of W rows. Returns
+        (h, cache)."""
+        cfg, (B, S) = self.cfg, h.shape[:2]
+        cache, slots = into if into is not None else (
+            self.init_cache(B, W), jnp.arange(B))
+        first = 0
+        for name in ("dense_blocks", "blocks"):
+            if name not in p:
+                continue
+            n = jax.tree.leaves(p[name])[0].shape[0]
+
+            def body(carry, xs, dense=name == "dense_blocks"):
+                x, cache = carry
+                bp, layer = xs
+                if dense:
+                    y, (c, k_pe) = tfm.dense_block_prefill(bp, x, positions,
+                                                           cfg)
+                else:
+                    y, (c, k_pe), _ = tfm.moe_block_prefill(
+                        bp, x, positions, cfg, mesh=self.mesh)
+                for key, rows in self._latent_rows(c, k_pe).items():
+                    cache[key] = cache[key].at[layer, slots, :S].set(rows)
+                return (y, cache), None
+            (h, cache), _ = jax.lax.scan(_remat(body, cfg.remat),
+                                         (h, dict(cache)),
+                                         (p[name], first + jnp.arange(n)))
+            first += n
+        return h, cache
+
+    def _decode_latent(self, p, cache, x, pos):
+        """Decode through the leading dense blocks, then the main stack,
+        each layer updating its rows of the latent cache in place (the
+        cache rides in the scan's carry, so no cache-sized copy is
+        made). Returns (x, cache, held (B,))."""
+        held = jnp.zeros((x.shape[0],), jnp.int32)
+        first = 0
+        for name in ("dense_blocks", "blocks"):
+            if name not in p:
+                continue
+            n = jax.tree.leaves(p[name])[0].shape[0]
+
+            def body(carry, xs):
+                x, cache, held = carry
+                bp, layer = xs
+                y, cache, h = tfm.latent_block_decode(bp, x, cache, layer,
+                                                      pos, self.cfg,
+                                                      mesh=self.mesh)
+                return (y, cache, held + h), None
+            (x, cache, held), _ = jax.lax.scan(
+                body, (x, cache, held), (p[name], first + jnp.arange(n)))
+            first += n
+        return x, cache, held
 
     # ------------------------------------------------------------------
     # decode loop: K fused decode+sample steps per host dispatch
@@ -591,19 +717,28 @@ class Model:
         position is idempotent for KV families and only perturbs state
         the host will overwrite on re-admission for recurrent families.
 
-        Returns (cache, token, pos, emitted, done, toks, live) with toks
-        and live shaped (n_tokens, B): token k belongs to slot b's output
-        stream iff live[k, b] (slots freeze monotonically, so the live
-        column is a prefix mask).
+        Returns (cache, token, pos, emitted, done, toks, live, held) with
+        toks and live shaped (n_tokens, B): token k belongs to slot b's
+        output stream iff live[k, b] (slots freeze monotonically, so the
+        live column is a prefix mask). held (n_tokens, B): each step's
+        assignments to the experts held here, for a MoE model (frozen
+        slots included; mask with live); None otherwise.
 
         The carry signature is donation-safe: every carried array is
         returned with identical shape/dtype, so callers can jit with
         donate_argnums over (cache, token, pos, emitted, done) and the
         KV cache updates in place instead of round-tripping.
         """
+        routed = self.cfg.family == "moe"
+
         def step(carry, key):
             cache, token, pos, emitted, done = carry
-            logits, cache = self.decode_step(p, cache, token, pos)
+            if routed:
+                logits, cache, held = self.decode_step(p, cache, token, pos,
+                                                       routed=True)
+            else:
+                logits, cache = self.decode_step(p, cache, token, pos)
+                held = None
             tok = sample_fn(logits, key)
             live = ~done
             tok = jnp.where(live, tok, token[:, 0]).astype(jnp.int32)
@@ -612,11 +747,13 @@ class Model:
             pos = pos + inc
             done = done | (emitted >= max_new) | (live & (eos >= 0) &
                                                   (tok == eos))
-            return (cache, tok[:, None], pos, emitted, done), (tok, live)
+            return (cache, tok[:, None], pos, emitted, done), (tok, live,
+                                                               held)
 
-        (cache, token, pos, emitted, done), (toks, live) = jax.lax.scan(
-            step, (cache, token, pos, emitted, done), keys, length=n_tokens)
-        return cache, token, pos, emitted, done, toks, live
+        (cache, token, pos, emitted, done), (toks, live, held) = \
+            jax.lax.scan(step, (cache, token, pos, emitted, done), keys,
+                         length=n_tokens)
+        return cache, token, pos, emitted, done, toks, live, held
 
     # ------------------------------------------------------------------
     # counting
@@ -625,7 +762,10 @@ class Model:
         shapes = jax.eval_shape(self.init, jax.random.key(0))
         total = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
         if active_only and self.cfg.n_experts:
+            # routed experts held here, as many as a token reaches of them
+            # in expectation (top_k / n_experts of each)
             cfg = self.cfg
-            expert = cfg.n_layers * cfg.n_experts * 3 * cfg.d_model * cfg.d_ff
+            expert = (cfg.n_layers - cfg.first_k_dense) * cfg.n_held() * 3 \
+                * cfg.d_model * cfg.expert_ff()
             total = total - expert + expert * cfg.top_k // cfg.n_experts
         return total

@@ -14,6 +14,18 @@ Two weight layouts, one code path:
 
 The (T, E, C) one-hot einsum of the original GShard paper is replaced by a
 scatter-add into an (E_loc, C, d) buffer — O(T·k·d) instead of O(T·E·C).
+
+Capacity is a training setting: prefill and decode route dropless. On one
+device that is `_dropless_local`: the assignments sorted by expert and
+each projection one grouped matmul (`jax.lax.ragged_dot`) over the rows
+of the held experts only; under a mesh the dispatch sizes every buffer
+C = T.
+A config may hold only a share of the routed experts (`experts_held`, from
+expert 0): the router keeps all `n_experts` outputs and the layer returns
+the held experts' part of the result, the part one chip of an
+expert-parallel deployment computes; nothing stands in for the rest.
+Gates are the top-k softmax probabilities, renormalized unless
+`norm_topk` is false.
 """
 from __future__ import annotations
 
@@ -29,14 +41,15 @@ _MODEL_AXIS = "model"
 
 
 def init(key, cfg):
-    d, f, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    d, f, E = cfg.d_model, cfg.expert_ff(), cfg.n_experts
+    E_loc = cfg.n_held()
     dt = dtype_of(cfg)
     ks = jax.random.split(key, 4)
     return {
         "router": dense_init(ks[0], (d, E), jnp.float32),
-        "w1": dense_init(ks[1], (E, d, f), dt),
-        "w3": dense_init(ks[2], (E, d, f), dt),
-        "w2": dense_init(ks[3], (E, f, d), dt, scale=1.0 / np.sqrt(f)),
+        "w1": dense_init(ks[1], (E_loc, d, f), dt),
+        "w3": dense_init(ks[2], (E_loc, d, f), dt),
+        "w2": dense_init(ks[3], (E_loc, f, d), dt, scale=1.0 / np.sqrt(f)),
     }
 
 
@@ -49,12 +62,14 @@ def specs(cfg):
     }
 
 
-def _route(x32, router_w, k):
+def _route(x32, router_w, k, renorm=True):
     """x32: (T, d) fp32. Returns gates (T, k), expert ids (T, k), aux loss."""
     logits = x32 @ router_w  # (T, E)
     probs = jax.nn.softmax(logits, axis=-1)
     gates, idx = jax.lax.top_k(probs, k)
-    gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+    if renorm:
+        gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True),
+                                    1e-9)
     # Switch-style load-balancing aux loss.
     E = router_w.shape[-1]
     me = jnp.mean(probs, axis=0)
@@ -76,7 +91,8 @@ def _moe_local(x, router_w, w1, w3, w2, cfg, e_offset, axis_name=None,
     k = cfg.top_k
     act = act_fn(cfg.act)
 
-    gates, idx, aux = _route(x.astype(jnp.float32), router_w, k)
+    gates, idx, aux = _route(x.astype(jnp.float32), router_w, k,
+                             cfg.norm_topk)
 
     flat_e = idx.reshape(-1)                      # (T*k,) global expert ids
     flat_g = gates.reshape(-1).astype(jnp.float32)
@@ -150,22 +166,58 @@ def _apply_small_t(p, xt, cfg, mesh):
     )(xt, p["router"], p["w1"], p["w3"], p["w2"])
 
 
-def apply(p, x, cfg, mesh=None):
+def _dropless_local(x, router_w, w1, w3, w2, cfg):
+    """x: (T, d) -> (T, d) and (T,) int32: the local experts' gated sum
+    over the tokens routed to them, and each token's assignments that land
+    on them. The T * top_k assignments are sorted by expert, those to
+    experts held elsewhere last; the held experts' rows go through grouped
+    matmuls and the rest are neither computed nor added (their rows of a
+    grouped product are undefined and masked). Each token gathers its k
+    rows back (no scatter-add) and sums them, gated, in float32."""
+    T, d = x.shape
+    E_loc, k = w1.shape[0], cfg.top_k
+    gates, idx, _ = _route(x.astype(jnp.float32), router_w, k,
+                           cfg.norm_topk)
+    group = jnp.minimum(idx.reshape(-1), E_loc)      # E_loc: held elsewhere
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=E_loc + 1)[:E_loc].astype(jnp.int32)
+    xs = x[order // k]
+    act = act_fn(cfg.act)
+    h = act(jax.lax.ragged_dot(xs, w1, sizes)) * jax.lax.ragged_dot(
+        xs, w3, sizes)
+    out = jax.lax.ragged_dot(h, w2, sizes)
+    out = out[jnp.argsort(order)].reshape(T, k, d)   # back to (token, j)
+    g = jnp.where(idx < E_loc, gates, 0.0)[..., None]
+    y = jnp.sum(jnp.where(g > 0, out.astype(jnp.float32), 0.0) * g, axis=1)
+    return y.astype(x.dtype), jnp.sum(idx < E_loc, axis=-1).astype(jnp.int32)
+
+
+def apply(p, x, cfg, mesh=None, dropless=False):
     """x: (B, S, d) -> (B, S, d), aux loss. Uses shard_map when a mesh with a
-    'model' axis is active, plain local computation otherwise."""
+    'model' axis is active, plain local computation otherwise. `dropless`
+    (prefill and decode) keeps every token's assignments; inference has
+    no aux loss, so the second output is then each token's assignments
+    that land on the experts held here, (B, S) int32."""
     B, S, d = x.shape
     xt = x.reshape(B * S, d)
 
     if mesh is None or _MODEL_AXIS not in mesh.shape:
+        if dropless:
+            y, held = _dropless_local(xt, p["router"], p["w1"], p["w3"],
+                                      p["w2"], cfg)
+            return y.reshape(B, S, d), held.reshape(B, S)
         y, aux = _moe_local(xt, p["router"], p["w1"], p["w3"], p["w2"], cfg, 0)
         return y.reshape(B, S, d).astype(x.dtype), aux
+    if cfg.experts_held:
+        raise NotImplementedError("a held expert share runs on one device")
 
     small_t = int(os.environ.get("REPRO_MOE_SMALL_T", SMALL_T))
     m_sz = mesh.shape[_MODEL_AXIS]
     n_dat = int(np.prod([s for a, s in mesh.shape.items()
                          if a != _MODEL_AXIS]))
     if (B * S <= small_t and cfg.n_experts % m_sz == 0
-            and cfg.n_experts >= m_sz and cfg.d_ff % max(n_dat, 1) == 0):
+            and cfg.n_experts >= m_sz
+            and cfg.expert_ff() % max(n_dat, 1) == 0):
         y, aux = _apply_small_t(p, xt, cfg, mesh)
         return y.reshape(B, S, d).astype(x.dtype), aux
 
@@ -190,7 +242,8 @@ def apply(p, x, cfg, mesh=None):
         e_off = jax.lax.axis_index(_MODEL_AXIS) * w1.shape[0] if ep else 0
         return _moe_local(xt, router_w, w1, w3, w2, cfg, e_off,
                           axis_name=_MODEL_AXIS,
-                          mean_axes=tuple(mesh.axis_names))
+                          mean_axes=tuple(mesh.axis_names),
+                          capacity=xt.shape[0] if dropless else None)
 
     y, aux = shard_map(
         fn, mesh=mesh,
@@ -199,4 +252,6 @@ def apply(p, x, cfg, mesh=None):
         out_specs=(xs, P()),
         check_rep=False,
     )(xt, p["router"], p["w1"], p["w3"], p["w2"])
+    if dropless:               # the mesh holds every expert
+        aux = jnp.full((B, S), cfg.top_k, jnp.int32)
     return y.reshape(B, S, d).astype(x.dtype), aux

@@ -35,6 +35,22 @@ def mlp_specs(cfg):
     return {"w1": ("embed", "mlp"), "w3": ("embed", "mlp"), "w2": ("mlp", "embed")}
 
 
+# prefill's feed-forward runs this many tokens at a time (see ffn_chunked)
+PREFILL_FFN_TOKENS = 8192
+
+
+def ffn_chunked(fn, h):
+    """A token-wise feed-forward `fn` over h (B, S, d), in chunks of
+    PREFILL_FFN_TOKENS tokens (one lax.map step each) when the batch holds
+    more and they divide it, so that a long prefill's widest activations
+    (a dense MLP's hidden width, a dropless MoE's top_k gathered rows a
+    token) are a chunk's; whole otherwise."""
+    n, (B, S, d) = PREFILL_FFN_TOKENS, h.shape
+    if B * S <= n or (B * S) % n:
+        return fn(h)
+    return jax.lax.map(fn, h.reshape(B * S // n, 1, n, d)).reshape(B, S, d)
+
+
 def mlp_apply(p, x, cfg):
     act = act_fn(cfg.act)
     h = act(jnp.einsum("bsd,df->bsf", x, p["w1"])) * jnp.einsum(
@@ -45,14 +61,42 @@ def mlp_apply(p, x, cfg):
 
 
 # ---------------------------------------------------------------------------
-# Dense decoder block (llama/qwen/minicpm/internvl2 backbone)
+# Self-attention of a decoder block: GQA, or latent attention (MLA) whose
+# cache rows are (c_kv, k_pe) in place of (k, v)
+# ---------------------------------------------------------------------------
+
+def attn_init(key, cfg):
+    if cfg.attn_kind == "mla":
+        return attention.mla_init(key, cfg)
+    return attention.init(key, cfg)
+
+
+def attn_specs(cfg):
+    if cfg.attn_kind == "mla":
+        return attention.mla_specs(cfg)
+    return attention.specs(cfg)
+
+
+def attend(p, x, positions, cfg, block_skip=False):
+    """Full-sequence self-attention: (out, cache rows of the layer)."""
+    if cfg.attn_kind == "mla":
+        return attention.mla_attend_train(p, x, positions, cfg,
+                                          block_skip=block_skip)
+    a, k, v = attention.attend_train(p, x, positions, cfg,
+                                     block_skip=block_skip)
+    return a, (k, v)
+
+
+# ---------------------------------------------------------------------------
+# Dense decoder block (llama/qwen/minicpm/internvl2 backbone; the leading
+# dense layers of deepseek-v2)
 # ---------------------------------------------------------------------------
 
 def dense_block_init(key, cfg):
     ks = jax.random.split(key, 2)
     return {
         "n1": norm_init(cfg),
-        "attn": attention.init(ks[0], cfg),
+        "attn": attn_init(ks[0], cfg),
         "n2": norm_init(cfg),
         "mlp": mlp_init(ks[1], cfg),
     }
@@ -61,23 +105,25 @@ def dense_block_init(key, cfg):
 def dense_block_specs(cfg):
     return {
         "n1": norm_specs(cfg),
-        "attn": attention.specs(cfg),
+        "attn": attn_specs(cfg),
         "n2": norm_specs(cfg),
         "mlp": mlp_specs(cfg),
     }
 
 
 def dense_block_apply(p, x, positions, cfg, block_skip=False):
-    a, _, _ = attention.attend_train(p["attn"], norm(x, p["n1"], cfg), positions,
-                                     cfg, block_skip=block_skip)
+    a, _ = attend(p["attn"], norm(x, p["n1"], cfg), positions, cfg,
+                  block_skip=block_skip)
     x = x + a
     return x + mlp_apply(p["mlp"], norm(x, p["n2"], cfg), cfg)
 
 
 def dense_block_prefill(p, x, positions, cfg):
-    a, k, v = attention.attend_train(p["attn"], norm(x, p["n1"], cfg), positions, cfg)
+    a, rows = attend(p["attn"], norm(x, p["n1"], cfg), positions, cfg)
     x = x + a
-    return x + mlp_apply(p["mlp"], norm(x, p["n2"], cfg), cfg), (k, v)
+    y = ffn_chunked(lambda h: mlp_apply(p["mlp"], h, cfg),
+                    norm(x, p["n2"], cfg))
+    return x + y, rows
 
 
 def dense_block_decode(p, x, ck, cv, pos, cfg, ring=False, scales=None):
@@ -92,67 +138,98 @@ def dense_block_decode(p, x, ck, cv, pos, cfg, ring=False, scales=None):
 
 
 # ---------------------------------------------------------------------------
-# MoE decoder block (mixtral / arctic). arctic adds a parallel dense
-# residual MLP alongside the MoE FFN (dense-MoE hybrid).
+# MoE decoder block (mixtral / arctic / deepseek-v2). arctic adds a parallel
+# dense residual MLP alongside the MoE FFN (dense-MoE hybrid); deepseek-v2
+# adds shared experts, one SwiGLU of n_shared x the expert width that every
+# token passes through.
 # ---------------------------------------------------------------------------
 
 def moe_block_init(key, cfg):
-    ks = jax.random.split(key, 3)
+    ks = jax.random.split(key, 4)
     p = {
         "n1": norm_init(cfg),
-        "attn": attention.init(ks[0], cfg),
+        "attn": attn_init(ks[0], cfg),
         "n2": norm_init(cfg),
         "moe": moe.init(ks[1], cfg),
     }
     if cfg.moe_dense_ff:
         p["dense_mlp"] = mlp_init(ks[2], cfg, d_ff=cfg.moe_dense_ff)
+    if cfg.n_shared_experts:
+        p["shared"] = mlp_init(ks[3], cfg,
+                               d_ff=cfg.n_shared_experts * cfg.expert_ff())
     return p
 
 
 def moe_block_specs(cfg):
     p = {
         "n1": norm_specs(cfg),
-        "attn": attention.specs(cfg),
+        "attn": attn_specs(cfg),
         "n2": norm_specs(cfg),
         "moe": moe.specs(cfg),
     }
     if cfg.moe_dense_ff:
         p["dense_mlp"] = mlp_specs(cfg)
+    if cfg.n_shared_experts:
+        p["shared"] = mlp_specs(cfg)
     return p
 
 
-def _moe_ffn(p, h, cfg, mesh):
-    y, aux = moe.apply(p["moe"], h, cfg, mesh=mesh)
+def _moe_ffn(p, h, cfg, mesh, dropless=False):
+    """The routed experts plus any dense residual or shared experts; the
+    second output is moe.apply's (held assignments when dropless)."""
+    y, aux = moe.apply(p["moe"], h, cfg, mesh=mesh, dropless=dropless)
     if cfg.moe_dense_ff:
         y = y + mlp_apply(p["dense_mlp"], h, cfg)
+    if cfg.n_shared_experts:
+        y = y + mlp_apply(p["shared"], h, cfg)
     return y, aux
 
 
 def moe_block_apply(p, x, positions, cfg, mesh=None, block_skip=False):
-    a, _, _ = attention.attend_train(p["attn"], norm(x, p["n1"], cfg), positions,
-                                     cfg, block_skip=block_skip)
+    a, _ = attend(p["attn"], norm(x, p["n1"], cfg), positions, cfg,
+                  block_skip=block_skip)
     x = x + a
     y, aux = _moe_ffn(p, norm(x, p["n2"], cfg), cfg, mesh)
     return x + y, aux
 
 
 def moe_block_prefill(p, x, positions, cfg, mesh=None):
-    a, k, v = attention.attend_train(p["attn"], norm(x, p["n1"], cfg), positions, cfg)
+    """Prefill routes dropless; the aux loss is a training term (0)."""
+    a, rows = attend(p["attn"], norm(x, p["n1"], cfg), positions, cfg)
     x = x + a
-    y, aux = _moe_ffn(p, norm(x, p["n2"], cfg), cfg, mesh)
-    return x + y, (k, v), aux
+    y = ffn_chunked(lambda h: _moe_ffn(p, h, cfg, mesh, dropless=True)[0],
+                    norm(x, p["n2"], cfg))
+    return x + y, rows, jnp.float32(0.0)
 
 
 def moe_block_decode(p, x, ck, cv, pos, cfg, mesh=None, ring=False,
                      scales=None):
+    """As dense_block_decode, plus each slot's assignments to the experts
+    held here (B,) as the last output."""
     out = attention.decode(p["attn"], norm(x, p["n1"], cfg), ck, cv, pos,
                            cfg, ring=ring, scales=scales)
     a, ck, cv = out[:3]
     x = x + a
-    y, _ = _moe_ffn(p, norm(x, p["n2"], cfg), cfg, mesh)
+    y, held = _moe_ffn(p, norm(x, p["n2"], cfg), cfg, mesh, dropless=True)
     if scales is not None:
-        return x + y, ck, cv, out[3]
-    return x + y, ck, cv
+        return x + y, ck, cv, out[3], held[:, 0]
+    return x + y, ck, cv, held[:, 0]
+
+
+def latent_block_decode(p, x, cache, layer, pos, cfg, mesh=None):
+    """One MLA block's decode step (dense if it has an "mlp", else MoE)
+    against the whole latent cache, which it updates in place at
+    `layer`. Returns (y, cache, held): held as in moe_block_decode, 0
+    for a dense block."""
+    a, cache = attention.mla_decode(p["attn"], norm(x, p["n1"], cfg), cache,
+                                    layer, pos, cfg)
+    x = x + a
+    h = norm(x, p["n2"], cfg)
+    if "mlp" in p:
+        return (x + mlp_apply(p["mlp"], h, cfg), cache,
+                jnp.zeros((x.shape[0],), jnp.int32))
+    y, held = _moe_ffn(p, h, cfg, mesh, dropless=True)
+    return x + y, cache, held[:, 0]
 
 
 # ---------------------------------------------------------------------------

@@ -5,7 +5,9 @@ be diffed field-by-field against the analytic profiles.
 Byte model (mirrors `workloads.profiler._bytes_classes`):
   weights      one stream of active params x 2 bytes/step (x3 training)
   kv           per resident row per layer, (K+V) x n_kv_heads x head_dim
-               x itemsize bytes (itemsize 1 for int8 KV, else 2); the
+               x itemsize bytes, or (kv_lora_rank + qk_rope_dim) x
+               itemsize for a latent (MLA) cache (itemsize 1 for int8
+               KV, else 2); the
                measured resident rows come from the window's
                `kv_row_steps` integral instead of the analytic
                batch x seq_len assumption
@@ -30,7 +32,7 @@ from repro.runtime.telemetry import TelemetryWindow
 def kv_row_bytes(cfg) -> float:
     """Bytes one KV-cache row (one token position, one layer) occupies."""
     itemsize = 1.0 if cfg.kv_dtype == "int8" else 2.0
-    return 2.0 * cfg.n_kv_heads * cfg.hd() * itemsize
+    return float(cfg.cache_row_elems()) * itemsize
 
 
 def kv_stream_bytes(win: TelemetryWindow, cfg) -> float:

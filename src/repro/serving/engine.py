@@ -45,6 +45,16 @@ the np token/live arrays pulled once per chunk, host-tracked per-slot
 context lengths, python queue depths — so an attached collector adds
 ZERO device syncs and cannot perturb token streams (asserted in
 tests/test_runtime.py).
+
+Spans and counters (`repro.core.trace`, recorded only while a recording
+is open, at host boundaries): `serve.admit` around each prefill dispatch
+(attrs `rows`, the prompt rows, and `padded_rows`, with the batch
+bucket's padding), `serve.chunk` around each decode dispatch; at each
+chunk's existing sync, `serve.slot_steps` (every slot's steps the chunk
+computed), `serve.slot_steps_live` (the steps that emitted a token) and,
+for a MoE model, `moe.held_assignments` (live tokens' expert assignments
+that land on the experts held here), all from the arrays that sync
+already brings to the host.
 """
 from __future__ import annotations
 
@@ -58,6 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace
 from repro.models.model import Model
 from repro.serving.sampling import sample_host, sample_tokens
 
@@ -185,13 +196,20 @@ class ServeEngine:
             so bucket padding never touches a live slot."""
             idx, r_topk, r_maxnew, r_eos = meta_i
             key, sub = jax.random.split(key)
-            logits, rows, rpos = self.model.prefill(p, batch, W=self.window)
+            if self.cfg.attn_kind == "mla":     # rows written in place
+                logits, cache, rpos = self.model.prefill(
+                    p, batch, W=self.window, into=(cache, idx))
+                rows = None
+            else:
+                logits, rows, rpos = self.model.prefill(p, batch,
+                                                        W=self.window)
             tok = sample_tokens(logits, sub, r_temp, r_topk,
                                 k_max=self.top_k_max)
             fin = (r_maxnew <= 1) | ((r_eos >= 0) & (tok == r_eos))
-            cache = jax.tree.map(
-                lambda c, rc: c.at[:, idx].set(rc.astype(c.dtype)),
-                cache, rows)
+            if rows is not None:
+                cache = jax.tree.map(
+                    lambda c, rc: c.at[:, idx].set(rc.astype(c.dtype)),
+                    cache, rows)
             pos = pos.at[idx].set(rpos)
             last_tok = last_tok.at[idx, 0].set(tok)
             emitted = emitted.at[idx].set(1)
@@ -257,8 +275,13 @@ class ServeEngine:
         groups = {}
         for slot, req in take:
             groups.setdefault(len(req.prompt), []).append((slot, req))
-        for items in groups.values():
-            self._admit_group(items)
+        for p_len, items in groups.items():
+            with trace.span("serve.admit") as sp:
+                if sp is not None:
+                    bucket = 1 << (len(items) - 1).bit_length()
+                    sp.attrs.update(rows=len(items) * p_len,
+                                    padded_rows=bucket * p_len)
+                self._admit_group(items)
         if self.mode == "host":
             self.last_tok = jnp.asarray(np.array(self._tok_np))
 
@@ -378,25 +401,33 @@ class ServeEngine:
     # stepping
     # ------------------------------------------------------------------
     def _dispatch_chunk(self):
-        """Launch one fused K-token decode; returns the (K, slots) token
-        and live-mask device arrays WITHOUT syncing."""
-        (self.cache, self.last_tok, self.pos, self.emitted, self.done_mask,
-         toks, live, self.key) = self._decode_chunk(
-            self.params, self.cache, self.last_tok, self.pos, self.emitted,
-            self.done_mask, self._temp_d, self._topk_d, self._maxnew_d,
-            self._eos_d, self.key)
+        """Launch one fused K-token decode; returns the (K, slots) token,
+        live-mask and held-assignment (MoE, else None) device arrays
+        WITHOUT syncing."""
+        with trace.span("serve.chunk"):
+            (self.cache, self.last_tok, self.pos, self.emitted,
+             self.done_mask, toks, live, held, self.key) = \
+                self._decode_chunk(
+                    self.params, self.cache, self.last_tok, self.pos,
+                    self.emitted, self.done_mask, self._temp_d,
+                    self._topk_d, self._maxnew_d, self._eos_d, self.key)
         for slot, req in enumerate(self.active):
             if req is not None:
                 self._pred[slot] = min(self._pred[slot] + self.decode_chunk,
                                        req.max_new_tokens)
-        return toks, live, list(self.active)
+        return toks, live, held, list(self.active)
 
-    def _reconcile(self, toks, live, snapshot):
+    def _reconcile(self, toks, live, held, snapshot):
         """Fold a (K, slots) chunk back into the request streams recorded
         at dispatch time and retire finished slots (one blocking sync)."""
-        toks, live = jax.device_get((toks, live))
+        toks, live, held = jax.device_get((toks, live, held))
         self.host_syncs += 1
         toks, live = np.asarray(toks), np.asarray(live)
+        trace.count("serve.slot_steps", live.size)
+        trace.count("serve.slot_steps_live", int(live.sum()))
+        if held is not None:
+            trace.count("moe.held_assignments",
+                        int((np.asarray(held) * live).sum()))
         if self.telemetry is not None:
             # the done mask freezes monotonically inside a chunk, so
             # per-slot emitted counts are the live-mask column sums —

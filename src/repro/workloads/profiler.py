@@ -118,7 +118,7 @@ def _bytes_classes(cfg, shape):
     if shape.kind != "train":
         W = min(shape.seq_len, cfg.sliding_window or shape.seq_len)
         kv = (2.0 * cfg.n_layers * shape.global_batch * W
-              * cfg.n_kv_heads * cfg.hd() * 2)
+              * cfg.cache_row_elems())
         if cfg.ssm_state:
             kv += (cfg.n_layers * shape.global_batch * 4
                    * (cfg.d_model * cfg.ssm_expand // max(cfg.ssm_headdim, 1))

@@ -217,3 +217,48 @@ def test_self_time_matches_the_benchmarks():
         assert rec.self_time("p", kids) == pytest.approx(
             bench_spans.self_time(outside, "p", kids))
     assert rec.self_time("p", ["c"]) == pytest.approx(7.5)
+
+
+def test_serving_engine_spans_and_counters():
+    """The engine's admission and decode-chunk spans and its slot and
+    held-expert counters, read from the arrays its chunk sync already
+    brings back: recorded under a recording, absent without one, and the
+    same number of host syncs either way. Every expert is held here, so
+    each live token makes top_k assignments in each MoE layer."""
+    import dataclasses
+
+    import numpy as np
+
+    from repro.configs import get_config
+    from repro.models.model import Model
+    from repro.serving import ServeEngine
+    from repro.serving.engine import Request
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite").reduced(),
+                              dtype="float32")
+    params = Model(cfg).init(jax.random.key(0))
+    lens = [(8, 5), (8, 7), (6, 3)]
+
+    def serve():
+        eng = ServeEngine(cfg, params, n_slots=2, window=24, decode_chunk=4)
+        rng = np.random.default_rng(1)
+        for i, (p, o) in enumerate(lens):
+            eng.submit(Request(i, rng.integers(0, cfg.vocab_size, p).astype(
+                np.int32), max_new_tokens=o))
+        eng.run()
+        return eng
+
+    with trace.recording() as idle:
+        pass
+    off = serve()
+    with trace.recording() as rec:
+        on = serve()
+    assert idle.spans == [] and not idle.counters
+    assert on.host_syncs == off.host_syncs
+    admits, chunks = rec.named("serve.admit"), rec.named("serve.chunk")
+    assert [(s.attrs["rows"], s.attrs["padded_rows"]) for s in admits] == \
+        [(16, 16), (6, 6)]
+    c = rec.counters
+    assert c["serve.slot_steps"] == len(chunks) * 4 * 2
+    assert c["serve.slot_steps_live"] == sum(o - 1 for _, o in lens)
+    assert c["moe.held_assignments"] == cfg.top_k * (
+        cfg.n_layers - cfg.first_k_dense) * c["serve.slot_steps_live"]
