@@ -6,6 +6,10 @@ prefill never materializes an (S, S) score matrix and the HLO stays small
 (one while body per loop — see launch/hlo_analysis.py for trip-count-aware
 costing).
 
+Decode reads and writes one stacked cache of every layer's rows, (L, B, W,
+K*hd) with kv heads and head width merged in the minor dim, in place
+(`decode` below).
+
 `block_skip=True` enables causal block skipping (lax.cond around fully
 masked KV blocks) — a §Perf hillclimb knob; baseline computes all blocks
 with masking.
@@ -263,18 +267,27 @@ def quantize_kv(k, axis=-1):
     return q, s.astype(jnp.bfloat16)
 
 
-def decode(p, x, cache_k, cache_v, pos, cfg, *, use_rope=True, ring=False,
-           scales=None):
-    """x: (B, 1, d); cache_k/v: (B, W, K, hd); pos: (B,) int32 current index.
+def decode(p, x, cache, layer, pos, cfg, *, use_rope=True, ring=False):
+    """Decode of one token against a stacked K/V-head cache, in place.
 
-    Returns (out (B,1,d), new_k_cache, new_v_cache[, new_scales]). If
-    `ring`, the cache is a sliding-window ring buffer indexed by pos % W.
-    `scales`: (ks, vs) each (B, W, K) for int8 caches (§Perf hillclimb #3:
-    halves the decode-dominant cache-read traffic; dequant is folded into
-    the score/value einsums so no bf16 cache copy materializes).
+    x: (B, 1, d); cache: {"k", "v": (L, B, W, K*hd)}, each row's kv heads
+    and head width merged in the minor dim as the dots read them (qwen2's
+    2 x 64 fill the 128 lanes), and for an int8 cache per-row-per-head
+    scales {"ksc", "vsc": (L, B, W, K)} (§Perf hillclimb #3: halves the
+    decode-dominant cache-read traffic; dequant is folded into the
+    scores and weights so no bf16 copy materializes); layer: this
+    layer's index into it; pos: (B,) position. Writes the position's row
+    at [layer, b, slot] (slot = pos % W if `ring`, a sliding-window ring
+    buffer), then attends over the layer's rows as stored: each head's
+    query sits in its kv group's hd lanes of a block-diagonal K*hd query
+    (zeros elsewhere), so the scores are one dot against the rows and
+    each head keeps its group's lanes of the weighted sum of rows. The
+    dots read the rows where they lie: no transposed or reshaped copy,
+    and no copy of the cache, which rides in the layer scan's carry.
+    Returns (out (B, 1, d), cache).
     """
-    B, _, d = x.shape
-    W = cache_k.shape[1]
+    B = x.shape[0]
+    W = cache["k"].shape[2]
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
@@ -283,46 +296,48 @@ def decode(p, x, cache_k, cache_v, pos, cfg, *, use_rope=True, ring=False,
     if use_rope:
         q = rope(q, pos[:, None], cfg.rope_theta)
         k = rope(k, pos[:, None], cfg.rope_theta)
+    H, K, hd = q.shape[2], k.shape[2], k.shape[3]
+    G = H // K
 
     slot = jnp.mod(pos, W) if ring else jnp.minimum(pos, W - 1)
     bidx = jnp.arange(B)
-    if scales is not None:
-        ks, vs = scales
-        kq, ksc = quantize_kv(k[:, 0])
-        vq, vsc = quantize_kv(v[:, 0])
-        cache_k = cache_k.at[bidx, slot].set(kq)
-        cache_v = cache_v.at[bidx, slot].set(vq)
-        ks = ks.at[bidx, slot].set(ksc)
-        vs = vs.at[bidx, slot].set(vsc)
-    else:
-        cache_k = cache_k.at[bidx, slot].set(k[:, 0].astype(cache_k.dtype))
-        cache_v = cache_v.at[bidx, slot].set(v[:, 0].astype(cache_v.dtype))
+    int8 = "ksc" in cache
+    new = dict(cache)
+    for name, row in (("k", k[:, 0]), ("v", v[:, 0])):
+        if int8:
+            row, sc = quantize_kv(row)
+            new[name + "sc"] = cache[name + "sc"].at[layer, bidx,
+                                                     slot].set(sc)
+        new[name] = cache[name].at[layer, bidx, slot].set(
+            row.reshape(B, K * hd).astype(cache[name].dtype))
+    rows = {n: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+            for n, a in new.items()}
 
-    H, hd = q.shape[2], q.shape[3]
-    K = cache_k.shape[2]
-    G = H // K
-    qg = q.reshape(B, 1, K, G, hd)
-    s = jnp.einsum("bqkgh,bskh->bkgqs", qg,
-                   cache_k.astype(qg.dtype),
+    def per_group(s, sc):
+        # s (B, H, W) times each head's kv-group scale, sc (B, W, K)
+        s = s.reshape(B, K, G, W) * sc.astype(jnp.float32).transpose(
+            0, 2, 1)[:, :, None]
+        return s.reshape(B, H, W)
+
+    dt = q.dtype
+    own = (np.arange(H) // G)[:, None] == np.arange(K)     # (H, K)
+    q_bd = jnp.where(own[:, :, None], q[:, 0, :, None], 0).reshape(
+        B, H, K * hd)
+    s = jnp.einsum("bhc,bwc->bhw", q_bd, rows["k"].astype(dt),
                    preferred_element_type=jnp.float32) / np.sqrt(hd)
-    if scales is not None:
-        s = s * ks.astype(jnp.float32).transpose(0, 2, 1)[:, :, None, None, :]
-    slots = jnp.arange(W)
+    if int8:
+        s = per_group(s, rows["ksc"])
+    valid = jnp.arange(W)[None] <= slot[:, None]
     if ring:
-        valid = (slots[None] <= slot[:, None]) | (pos[:, None] >= W)
-    else:
-        valid = slots[None] <= slot[:, None]
-    s = jnp.where(valid[:, None, None, None], s, NEG_INF)
+        valid |= pos[:, None] >= W
+    s = jnp.where(valid[:, None], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
-    if scales is not None:
-        w = w * vs.astype(jnp.float32).transpose(0, 2, 1)[:, :, None, None, :]
-    o = jnp.einsum("bkgqs,bskh->bkgqh", w.astype(qg.dtype),
-                   cache_v.astype(qg.dtype))
-    o = o.transpose(0, 3, 1, 2, 4).reshape(B, 1, H, hd)
-    o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    if scales is not None:
-        return o, cache_k, cache_v, (ks, vs)
-    return o, cache_k, cache_v
+    if int8:
+        w = per_group(w, rows["vsc"])
+    o = jnp.einsum("bhw,bwc->bhc", w.astype(dt), rows["v"].astype(dt))
+    o = jnp.sum(jnp.where(own[:, :, None], o.reshape(B, H, K, hd), 0),
+                axis=2)
+    return jnp.einsum("bhk,hkd->bd", o, p["wo"])[:, None], new
 
 
 def cross_decode(p, x, cross_k, cross_v, kv_len=None):
@@ -341,18 +356,16 @@ def cross_decode(p, x, cross_k, cross_v, kv_len=None):
 
 
 def seed_ring_cache(k, v, window):
-    """Convert full prefill K/V (B, S, K, hd) into a ring cache of size W
+    """Convert full prefill rows (B, S, ...) into a ring cache of W rows
     positioned such that slot = pos % W, ready for decode at pos = S."""
-    B, S, K, hd = k.shape
+    B, S = k.shape[:2]
     W = window
+    ring = lambda a: jnp.zeros((B, W) + a.shape[2:], a.dtype)
     if S <= W:
-        ck = jnp.zeros((B, W, K, hd), k.dtype).at[:, :S].set(k)
-        cv = jnp.zeros((B, W, K, hd), v.dtype).at[:, :S].set(v)
-        return ck, cv
+        return ring(k).at[:, :S].set(k), ring(v).at[:, :S].set(v)
     idx = np.mod(np.arange(S - W, S), W)
-    ck = jnp.zeros((B, W, K, hd), k.dtype).at[:, idx].set(k[:, S - W:])
-    cv = jnp.zeros((B, W, K, hd), v.dtype).at[:, idx].set(v[:, S - W:])
-    return ck, cv
+    return (ring(k).at[:, idx].set(k[:, S - W:]),
+            ring(v).at[:, idx].set(v[:, S - W:]))
 
 
 # ---------------------------------------------------------------------------

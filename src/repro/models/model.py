@@ -23,7 +23,9 @@ API:
                                               (+ held (B,), routed=True)
   decode_loop(params, cache, token, pos, emitted, max_new, done, eos,
               sample_fn, keys, n_tokens=K) -> fused K-token decode scan
-  init_cache(B, W)                         -> zeroed cache tree
+  init_cache(B, W)                         -> zeroed cache tree (K/V rows
+                                              (L, B, W, K*hd), decoded in
+                                              place)
   cache_specs(W)                           -> logical-axis tree for cache
   param_count(active_only=False)
 """
@@ -274,9 +276,15 @@ class Model:
         return cfg.sliding_window if cfg.sliding_window else seq_len
 
     def init_cache(self, B, W):
+        """Zeroed cache of B slots of W rows. A K/V-head cache holds one
+        stacked array per K and V, (L, B, W, K*hd): each row's kv heads
+        and head width merged in the minor dim as decode's dots read
+        them, (L, B, W, K) for the int8 scales; decode carries it
+        through the layer scan and writes each new row in place."""
         cfg = self.cfg
         dt = dtype_of(cfg)
         K, hd, L = cfg.n_kv_heads, cfg.hd(), cfg.n_layers
+        C = K * hd
         fam = cfg.family
         if fam in ("dense", "vlm", "moe") and cfg.attn_kind == "mla":
             # a latent cache is kept in kv_dtype (int8 with per-row scales)
@@ -291,16 +299,18 @@ class Model:
         if fam in ("dense", "vlm", "moe"):
             W = self.kv_window(W)
             if self._int8_kv():
-                return {"k": jnp.zeros((L, B, W, K, hd), jnp.int8),
-                        "v": jnp.zeros((L, B, W, K, hd), jnp.int8),
+                return {"k": jnp.zeros((L, B, W, C), jnp.int8),
+                        "v": jnp.zeros((L, B, W, C), jnp.int8),
                         "ksc": jnp.zeros((L, B, W, K), jnp.bfloat16),
                         "vsc": jnp.zeros((L, B, W, K), jnp.bfloat16)}
-            return {"k": jnp.zeros((L, B, W, K, hd), dt),
-                    "v": jnp.zeros((L, B, W, K, hd), dt)}
+            return {"k": jnp.zeros((L, B, W, C), dt),
+                    "v": jnp.zeros((L, B, W, C), dt)}
         if fam == "audio":
+            # the cross cache (encoder K/V, written once by prefill) is
+            # read per layer and keeps its (K, hd) minor dims
             F = cfg.enc_frames
-            return {"k": jnp.zeros((L, B, W, K, hd), dt),
-                    "v": jnp.zeros((L, B, W, K, hd), dt),
+            return {"k": jnp.zeros((L, B, W, C), dt),
+                    "v": jnp.zeros((L, B, W, C), dt),
                     "xk": jnp.zeros((L, B, F, K, hd), dt),
                     "xv": jnp.zeros((L, B, F, K, hd), dt)}
         if fam == "hybrid":
@@ -310,8 +320,8 @@ class Model:
                 "conv": jnp.zeros((L, B, cfg.conv_kernel - 1, cdim), dt),
                 "ssm": jnp.zeros((L, B, nh, cfg.ssm_headdim, cfg.ssm_state),
                                  jnp.float32),
-                "k": jnp.zeros((napp, B, W, K, hd), dt),
-                "v": jnp.zeros((napp, B, W, K, hd), dt),
+                "k": jnp.zeros((napp, B, W, C), dt),
+                "v": jnp.zeros((napp, B, W, C), dt),
             }
         if fam == "ssm":
             inner, nh, hq, hv = xlstm.m_dims(cfg)
@@ -339,20 +349,22 @@ class Model:
 
     def cache_specs(self):
         fam = self.cfg.family
-        kv = ("layers", "batch", "kv_seq", "kv_heads", "head_dim")
-        sc = ("layers", "batch", "kv_seq", "kv_heads")
+        # a K/V-head row's merged kv heads x head width (and an int8
+        # row's per-head scales) map as kv heads
+        kv = ("layers", "batch", "kv_seq", "kv_heads")
         if fam in ("dense", "vlm", "moe") and self.cfg.attn_kind == "mla":
             lat = {"ckv": ("layers", "batch", "kv_seq", "kv_lora"),
                    "kpe": ("layers", "batch", "kv_seq", None)}
             if self._int8_kv():
-                lat.update(ckv_sc=sc[:3], kpe_sc=sc[:3])
+                lat.update(ckv_sc=kv[:3], kpe_sc=kv[:3])
             return lat
         if fam in ("dense", "vlm", "moe"):
             if self._int8_kv():
-                return {"k": kv, "v": kv, "ksc": sc, "vsc": sc}
+                return {"k": kv, "v": kv, "ksc": kv, "vsc": kv}
             return {"k": kv, "v": kv}
         if fam == "audio":
-            return {"k": kv, "v": kv, "xk": kv, "xv": kv}
+            return {"k": kv, "v": kv, "xk": kv + ("head_dim",),
+                    "xv": kv + ("head_dim",)}
         if fam == "hybrid":
             return {"conv": ("layers", "batch", None, "conv_dim"),
                     "ssm": ("layers", "batch", "ssm_heads", None, None),
@@ -383,14 +395,14 @@ class Model:
         ring = cfg.sliding_window > 0
 
         def pad_kv(k):
-            # k: (L, B, S, K, hd) -> (L, B, W_eff, K, hd)
+            # k: (L, B, S, K, hd) -> rows (L, B, W_eff, K*hd); int8
+            # scales (L, B, S, K) keep their K
+            k = k.reshape(*k.shape[:3], -1)
             Sk = k.shape[2]
             W_eff = self.kv_window(W or Sk)
             if W_eff == Sk:
                 return k
-            pad = [(0, 0)] * k.ndim
-            pad[2] = (0, W_eff - Sk)
-            return jnp.pad(k, pad)
+            return jnp.pad(k, ((0, 0), (0, 0), (0, W_eff - Sk), (0, 0)))
 
         if fam in ("dense", "vlm", "moe") and cfg.attn_kind == "mla":
             h = self._embed(p, tokens)
@@ -419,13 +431,17 @@ class Model:
             h, (ks, vs) = jax.lax.scan(_remat(body, cfg.remat), h, p["blocks"])
             if ring:
                 Wr = cfg.sliding_window
+                merge = lambda a: a.reshape(*a.shape[:3], -1)
                 seeded = jax.vmap(
-                    lambda a, b: attention.seed_ring_cache(a, b, Wr))(ks, vs)
+                    lambda a, b: attention.seed_ring_cache(a, b, Wr))(
+                        merge(ks), merge(vs))
                 cache = {"k": seeded[0], "v": seeded[1]}
             elif self._int8_kv():
-                kq, ksc = attention.quantize_kv(pad_kv(ks))
-                vq, vsc = attention.quantize_kv(pad_kv(vs))
-                cache = {"k": kq, "v": vq, "ksc": ksc, "vsc": vsc}
+                # per-row-per-head scales, rows merged after quantizing
+                kq, ksc = attention.quantize_kv(ks)
+                vq, vsc = attention.quantize_kv(vs)
+                cache = {"k": pad_kv(kq), "v": pad_kv(vq),
+                         "ksc": pad_kv(ksc), "vsc": pad_kv(vsc)}
             else:
                 cache = {"k": pad_kv(ks), "v": pad_kv(vs)}
 
@@ -518,7 +534,11 @@ class Model:
     def decode_step(self, p, cache, token, pos, routed=False):
         """token: (B, 1) int32; pos: (B,) int32. Returns (logits, cache);
         with `routed` (MoE) also each slot's assignments to the experts
-        held here, summed over layers (B,) int32."""
+        held here, summed over layers (B,) int32. A K/V-head or latent
+        cache rides in the layer scan's carry: each layer writes its new
+        row at [layer, b, slot] (slot = pos, or pos % W in a ring) and
+        attends over its rows in place, so the step makes no cache-sized
+        copy."""
         cfg, mesh = self.cfg, self.mesh
         fam = cfg.family
         x = self._embed(p, token)
@@ -529,44 +549,31 @@ class Model:
             x, cache, held = self._decode_latent(p, cache, x, pos)
 
         elif fam in ("dense", "vlm", "moe"):
-            int8 = self._int8_kv()
-            dec = tfm.moe_block_decode if fam == "moe" else \
-                tfm.dense_block_decode
-            kw = {"mesh": mesh} if fam == "moe" else {}
-
-            # a MoE block's last output is its held assignments (B,),
-            # stacked over layers as the scan's last output
-            if int8:
-                def body(x, xs):
-                    bp, ck, cv, ksc, vsc = xs
-                    out = dec(bp, x, ck, cv, pos, cfg, ring=ring,
-                              scales=(ksc, vsc), **kw)
-                    return out[0], out[1:3] + tuple(out[3]) + out[4:]
-                x, ys = jax.lax.scan(
-                    body, x, (p["blocks"], cache["k"], cache["v"],
-                              cache["ksc"], cache["vsc"]))
-                cache = dict(zip(("k", "v", "ksc", "vsc"), ys[:4]))
-            else:
-                def body(x, xs):
-                    bp, ck, cv = xs
-                    out = dec(bp, x, ck, cv, pos, cfg, ring=ring, **kw)
-                    return out[0], out[1:]
-                x, ys = jax.lax.scan(
-                    body, x, (p["blocks"], cache["k"], cache["v"]))
-                cache = {"k": ys[0], "v": ys[1]}
-            if fam == "moe":
-                held = jnp.sum(ys[-1], axis=0)
+            # the stacked cache rides in the carry, each layer writing its
+            # row in place; a MoE block also gives its held assignments
+            def body(carry, xs):
+                x, cache, held = carry
+                bp, layer = xs
+                y, cache, h = tfm.kv_block_decode(bp, x, cache, layer, pos,
+                                                  cfg, mesh=mesh, ring=ring)
+                return (y, cache, held + h), None
+            (x, cache, held), _ = jax.lax.scan(
+                body, (x, dict(cache), jnp.zeros((x.shape[0],), jnp.int32)),
+                (p["blocks"], jnp.arange(cfg.n_layers)))
 
         elif fam == "audio":
             x = x + sinusoid_at(pos, cfg.d_model).astype(x.dtype)
 
-            def body(x, xs):
-                bp, ck, cv, xk, xv = xs
-                y, ck, cv = tfm.xdec_block_decode(bp, x, ck, cv, xk, xv, pos, cfg)
-                return y, (ck, cv)
-            x, (ks, vs) = jax.lax.scan(
-                body, x, (p["dec"], cache["k"], cache["v"], cache["xk"], cache["xv"]))
-            cache = {"k": ks, "v": vs, "xk": cache["xk"], "xv": cache["xv"]}
+            def body(carry, xs):
+                x, kv = carry
+                bp, xk, xv, layer = xs
+                return tfm.xdec_block_decode(bp, x, kv, layer, xk, xv, pos,
+                                             cfg), None
+            (x, kv), _ = jax.lax.scan(
+                body, (x, {"k": cache["k"], "v": cache["v"]}),
+                (p["dec"], cache["xk"], cache["xv"],
+                 jnp.arange(cfg.n_layers)))
+            cache = dict(cache, **kv)
 
         elif fam == "hybrid":
             per = cfg.attn_every
@@ -579,18 +586,20 @@ class Model:
                 y, cs, st = ssm.decode_step(mp, x, cs, st, cfg)
                 return x + y, (cs, st)
 
-            def group(x, xs):
-                gp, gcs, gst, ck, cv = xs
+            # the shared block's application g attends over layer g of
+            # the stacked cache, carried as in the dense families
+            def group(carry, xs):
+                x, kv = carry
+                gp, gcs, gst, g = xs
                 x, (cs, st) = jax.lax.scan(inner, x, (gp, gcs, gst))
-                x, ck, cv = tfm.dense_block_decode(p["shared_attn"], x, ck, cv,
-                                                   pos, cfg)
-                return x, (cs, st, ck, cv)
-            x, (css, sts, ks, vs) = jax.lax.scan(
-                group, x, (mamba, r(cache["conv"]), r(cache["ssm"]),
-                           cache["k"], cache["v"]))
+                x, kv, _ = tfm.kv_block_decode(p["shared_attn"], x, kv, g,
+                                               pos, cfg)
+                return (x, kv), (cs, st)
+            (x, kv), (css, sts) = jax.lax.scan(
+                group, (x, {"k": cache["k"], "v": cache["v"]}),
+                (mamba, r(cache["conv"]), r(cache["ssm"]), jnp.arange(ng)))
             cache = {"conv": css.reshape(cfg.n_layers, *css.shape[2:]),
-                     "ssm": sts.reshape(cfg.n_layers, *sts.shape[2:]),
-                     "k": ks, "v": vs}
+                     "ssm": sts.reshape(cfg.n_layers, *sts.shape[2:]), **kv}
 
         elif fam == "ssm":
             per = cfg.slstm_every

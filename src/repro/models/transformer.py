@@ -126,17 +126,6 @@ def dense_block_prefill(p, x, positions, cfg):
     return x + y, rows
 
 
-def dense_block_decode(p, x, ck, cv, pos, cfg, ring=False, scales=None):
-    out = attention.decode(p["attn"], norm(x, p["n1"], cfg), ck, cv, pos,
-                           cfg, ring=ring, scales=scales)
-    a, ck, cv = out[:3]
-    x = x + a
-    y = x + mlp_apply(p["mlp"], norm(x, p["n2"], cfg), cfg)
-    if scales is not None:
-        return y, ck, cv, out[3]
-    return y, ck, cv
-
-
 # ---------------------------------------------------------------------------
 # MoE decoder block (mixtral / arctic / deepseek-v2). arctic adds a parallel
 # dense residual MLP alongside the MoE FFN (dense-MoE hybrid); deepseek-v2
@@ -202,24 +191,26 @@ def moe_block_prefill(p, x, positions, cfg, mesh=None):
     return x + y, rows, jnp.float32(0.0)
 
 
-def moe_block_decode(p, x, ck, cv, pos, cfg, mesh=None, ring=False,
-                     scales=None):
-    """As dense_block_decode, plus each slot's assignments to the experts
-    held here (B,) as the last output."""
-    out = attention.decode(p["attn"], norm(x, p["n1"], cfg), ck, cv, pos,
-                           cfg, ring=ring, scales=scales)
-    a, ck, cv = out[:3]
+def kv_block_decode(p, x, cache, layer, pos, cfg, mesh=None, ring=False):
+    """One GQA block's decode step (dense if it has an "mlp", else MoE)
+    against the stacked K/V-head cache, which it updates in place at
+    `layer`. Returns (y, cache, held): each slot's assignments to the
+    experts held here (B,), 0 for a dense block."""
+    a, cache = attention.decode(p["attn"], norm(x, p["n1"], cfg), cache,
+                                layer, pos, cfg, ring=ring)
     x = x + a
-    y, held = _moe_ffn(p, norm(x, p["n2"], cfg), cfg, mesh, dropless=True)
-    if scales is not None:
-        return x + y, ck, cv, out[3], held[:, 0]
-    return x + y, ck, cv, held[:, 0]
+    h = norm(x, p["n2"], cfg)
+    if "mlp" in p:
+        return (x + mlp_apply(p["mlp"], h, cfg), cache,
+                jnp.zeros((x.shape[0],), jnp.int32))
+    y, held = _moe_ffn(p, h, cfg, mesh, dropless=True)
+    return x + y, cache, held[:, 0]
 
 
 def latent_block_decode(p, x, cache, layer, pos, cfg, mesh=None):
     """One MLA block's decode step (dense if it has an "mlp", else MoE)
     against the whole latent cache, which it updates in place at
-    `layer`. Returns (y, cache, held): held as in moe_block_decode, 0
+    `layer`. Returns (y, cache, held): held as in kv_block_decode, 0
     for a dense block."""
     a, cache = attention.mla_decode(p["attn"], norm(x, p["n1"], cfg), cache,
                                     layer, pos, cfg)
@@ -293,9 +284,11 @@ def xdec_block_apply(p, x, enc_out, positions, cfg):
     return x + mlp_apply(p["mlp"], norm(x, p["n3"], cfg), cfg), (k, v), (xk, xv)
 
 
-def xdec_block_decode(p, x, ck, cv, xk, xv, pos, cfg):
-    a, ck, cv = attention.decode(p["attn"], norm(x, p["n1"], cfg), ck, cv, pos,
-                                 cfg, use_rope=False)
+def xdec_block_decode(p, x, cache, layer, xk, xv, pos, cfg):
+    """Self-attention against the stacked cache (updated in place at
+    `layer`), cross-attention against this layer's encoder K/V."""
+    a, cache = attention.decode(p["attn"], norm(x, p["n1"], cfg), cache,
+                                layer, pos, cfg, use_rope=False)
     x = x + a
     x = x + attention.cross_decode(p["xattn"], norm(x, p["n2"], cfg), xk, xv)
-    return x + mlp_apply(p["mlp"], norm(x, p["n3"], cfg), cfg), ck, cv
+    return x + mlp_apply(p["mlp"], norm(x, p["n3"], cfg), cfg), cache

@@ -2,7 +2,10 @@
 batching (vLLM-style at the granularity JAX's static shapes allow).
 
 The engine owns a fixed decode batch of `n_slots` sequences and a KV
-cache sized (slots, window). Requests are queued (deque, O(1) FIFO);
+cache sized (slots, window): for K/V heads one stacked array per K and V
+of (layers, slots, window, kv_heads * head_dim), the layout every program
+here reads and writes (prefill's rows are inserted as they come). Requests
+are queued (deque, O(1) FIFO);
 whenever slots free (EOS or max tokens) waiting requests are admitted in
 prompt-length groups: equal-length prompts prefill in ONE batched
 dispatch, with the batch dim padded to a power-of-two bucket so the
@@ -16,8 +19,9 @@ Decode is fully device-resident: `Model.decode_loop` fuses
 temperature via `jax.lax.top_k` + `jax.random.categorical`) into one
 jitted lax.scan whose carry (cache, feedback token, pos, emitted
 counter, done mask, PRNG key) is donated, so the KV cache updates in
-place and the host syncs ONCE per `decode_chunk` tokens instead of once
-per token. Finished slots (tokens-emitted >= max_new_tokens, or EOS
+place (each layer writes its row into the carried cache; the chunk makes
+no cache-sized copy) and the host syncs ONCE per `decode_chunk` tokens
+instead of once per token. Finished slots (tokens-emitted >= max_new_tokens, or EOS
 hit) freeze inside the chunk via the carried done mask, so a slot that
 stops mid-chunk emits exactly its budget.
 

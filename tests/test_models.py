@@ -182,3 +182,124 @@ def test_moe_small_t_path_matches_local():
     single-device computation (dropless both sides)."""
     if len(jax.devices()) < 2:
         pytest.skip("needs a mesh")
+
+
+def _plain_decode(p, x, cache, layer, pos, cfg, *, use_rope=True,
+                  ring=False):
+    """Reference for `attention.decode`: plain softmax attention of each
+    head over its kv head's rows of one layer, the rows viewed as
+    (B, W, K, hd) and dequantized (int8) before the dots, in float32."""
+    from repro.models.common import rope
+    B, W = x.shape[0], cache["k"].shape[2]
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
+    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
+    if "bq" in p:
+        q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
+    if use_rope:
+        q = rope(q, pos[:, None], cfg.rope_theta)
+        k = rope(k, pos[:, None], cfg.rope_theta)
+    q, k, v = q[:, 0], k[:, 0], v[:, 0]
+    H, K, hd = q.shape[1], k.shape[1], k.shape[2]
+    slot = pos % W if ring else jnp.minimum(pos, W - 1)
+    b = jnp.arange(B)
+    new = dict(cache)
+    for name, row in (("k", k), ("v", v)):
+        if name + "sc" in cache:
+            sc = jnp.maximum(jnp.max(jnp.abs(row.astype(jnp.float32)), -1)
+                             / 127.0, 1e-8)
+            row = jnp.clip(jnp.round(row.astype(jnp.float32) / sc[..., None]),
+                           -127, 127)
+            new[name + "sc"] = cache[name + "sc"].at[layer, b, slot].set(
+                sc.astype(jnp.bfloat16))
+        new[name] = cache[name].at[layer, b, slot].set(
+            row.reshape(B, K * hd).astype(cache[name].dtype))
+    kv = {}
+    for name in ("k", "v"):
+        rows = new[name][layer].reshape(B, W, K, hd).astype(jnp.float32)
+        if name + "sc" in new:
+            rows = rows * new[name + "sc"][layer].astype(jnp.float32)[..., None]
+        kv[name] = rows
+    valid = jnp.arange(W)[None] <= slot[:, None]
+    if ring:
+        valid = valid | (pos[:, None] >= W)
+    heads = []
+    for h in range(H):
+        g = h // (H // K)
+        s = jnp.einsum("bd,bwd->bw", q[:, h].astype(jnp.float32),
+                       kv["k"][:, :, g]) / np.sqrt(hd)
+        w = jax.nn.softmax(jnp.where(valid, s, -jnp.inf), axis=-1)
+        heads.append(jnp.einsum("bw,bwd->bd", w, kv["v"][:, :, g]))
+    o = jnp.stack(heads, axis=1).astype(x.dtype)
+    return jnp.einsum("bhk,hkd->bd", o, p["wo"])[:, None], new
+
+
+# every family whose decode attends over a K/V-head cache, at 2 kv heads
+# so that each kv head serves a group of 2 query heads
+STACKED_KV_CASES = {
+    "dense": ("qwen2-0.5b", {}, 12),
+    "dense_bf16": ("qwen2-0.5b", {"dtype": "bfloat16"}, 12),
+    "int8": ("qwen2-0.5b", {"kv_dtype": "int8"}, 12),
+    "ring": ("qwen2-0.5b", {"sliding_window": 8}, 8),
+    "moe": ("mixtral-8x7b", {"sliding_window": 0, "capacity_factor": 8.0},
+            12),
+    "vlm": ("internvl2-1b", {}, 16),
+    "hybrid": ("zamba2-2.7b", {}, 12),
+    "audio": ("whisper-large-v3", {}, 12),
+}
+
+
+@pytest.mark.parametrize("n_steps", [1, 4], ids=["step", "chunk"])
+@pytest.mark.parametrize("case", list(STACKED_KV_CASES))
+def test_stacked_kv_decode_matches_plain_attention(case, n_steps,
+                                                   monkeypatch):
+    """The in-place decode over the stacked (L, B, W, K*hd) cache gives
+    the logits and the cache that plain per-layer softmax attention over
+    the same rows gives: one step's logits, then the whole cache after
+    `n_steps` decode steps of one decode_loop (each step's rows of every
+    layer but the last depend on the attention below them). Slots sit at
+    different positions; the ring case crosses its wrap."""
+    from repro.models.model import Model
+    arch, over, W = STACKED_KV_CASES[case]
+    cfg = dataclasses.replace(get_config(arch).reduced(), n_kv_heads=2,
+                              **{"dtype": "float32", **over})
+    m = Model(cfg)
+    params = m.init(jax.random.key(0))
+    B, S = 2, 6
+    batch = {"tokens": jax.random.randint(jax.random.key(1), (B, S), 0,
+                                          cfg.vocab_size)}
+    if cfg.family == "vlm":
+        batch["patches"] = jax.random.normal(
+            jax.random.key(2), (B, cfg.n_patches, cfg.d_model)).astype(
+                cfg.dtype)
+    if cfg.family == "audio":
+        batch["frames"] = jax.random.normal(
+            jax.random.key(2), (B, cfg.enc_frames, cfg.d_model)).astype(
+                cfg.dtype)
+    _, cache, pos = m.prefill(params, batch, W=W)
+    pos = pos - jnp.asarray([0, 2], jnp.int32)
+    assert cache["k"].shape == (cache["k"].shape[0], B, W, 2 * cfg.hd())
+    toks = jax.random.randint(jax.random.key(3), (n_steps, B), 0,
+                              cfg.vocab_size)
+
+    def run():
+        logits, _ = m.decode_step(params, cache, toks[0][:, None], pos)
+        out = m.decode_loop(params, cache, toks[0][:, None], pos,
+                            jnp.zeros((B,), jnp.int32),
+                            jnp.full((B,), n_steps + 1, jnp.int32),
+                            jnp.zeros((B,), bool), jnp.full((B,), -1),
+                            lambda lg, t: t, toks, n_tokens=n_steps)
+        return logits, out[0]
+
+    logits, after = run()
+    monkeypatch.setattr(attention, "decode", _plain_decode)
+    ref_logits, ref_after = run()
+    tol = 2e-2 if cfg.dtype == "bfloat16" else 1e-4
+    np.testing.assert_allclose(np.asarray(logits, np.float32),
+                               np.asarray(ref_logits, np.float32),
+                               rtol=tol, atol=tol)
+    assert set(after) == set(ref_after)
+    for name in after:
+        np.testing.assert_allclose(np.asarray(after[name], np.float32),
+                                   np.asarray(ref_after[name], np.float32),
+                                   rtol=tol, atol=tol, err_msg=name)
